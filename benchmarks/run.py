@@ -25,6 +25,9 @@ def main() -> None:
     if args.out_dir:
         os.environ["BENCH_OUT_DIR"] = args.out_dir
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_cluster,
         bench_durability,

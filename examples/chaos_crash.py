@@ -39,7 +39,6 @@ def _cmd(args, wal_dir):
 
 def _env():
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env.setdefault("PYTHONPATH", "src")
     return env
 

@@ -59,6 +59,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import GnnPeConfig, GnnPeEngine, GraphUpdate, apply_graph_update, vf2_match
 from repro.graphs import newman_watts_strogatz, random_connected_query
 from repro.obs import parse_prometheus, to_prometheus, write_json_snapshot
@@ -374,6 +375,7 @@ def main():
         help="with --wal: epochs between snapshots",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = newman_watts_strogatz(args.n, k=4, p=0.1, n_labels=50, seed=0)
     print(f"[offline] building index over |V|={g.n_vertices} |E|={g.n_edges} ...")
@@ -390,7 +392,7 @@ def main():
         import jax
 
         print(
-            f"[offline] stacked probe over {len(jax.devices())} device(s): "
+            f"[offline] stacked probe over {len(jax.local_devices())} device(s): "
             f"{engine.offline_stats['stacked_bytes']/1e6:.1f} MB stacked tensors "
             f"({engine.offline_stats['stacked_padding_frac']:.0%} padding)"
         )

@@ -45,6 +45,7 @@ import numpy as np
 from ..graphs import Graph, Partitioning, expanded_partition, partition_graph
 from ..obs import trace as obs_trace
 from ..obs.metrics import REGISTRY as _OBS
+from ..shapes import pad_rows, pow2_at_least
 from .delta import (
     DeltaIndex,
     apply_graph_update,
@@ -316,7 +317,8 @@ class GnnPeEngine:
                     leaf_labels=self._relabel_leaves(stars.leaf_labels, stars.leaf_mask, i),
                 )
                 emb_i, _ = self._node_embeddings(
-                    g, vset, stars_i, multi_params[i], multi_res[i].fallback_vertices
+                    g, vset, stars_i, multi_params[i], multi_res[i].fallback_vertices,
+                    isolated=False,
                 )
                 node_emb_multi[i] = emb_i
             embed_time += time.perf_counter() - t2
@@ -517,28 +519,38 @@ class GnnPeEngine:
         out = self.label_perms[i][leaf_labels].astype(np.int32)
         return np.where(leaf_mask, out, 0)
 
-    def _node_embeddings(self, g, vset, stars, params, fallback_vertices):
-        """Embed every vertex of the expanded set; all-ones for overflow/fallback."""
-        cfg = self.cfg
+    def _embed_star_rows(self, params, center_labels, leaf_labels, leaf_mask, isolated=True):
+        """(o, o0) for a batch of stars; o0 is None unless ``isolated``.
+        The batch pads to a power-of-two bucket so the jitted encoder sees
+        a handful of recurring shapes instead of one per partition or
+        touched-set size; star embedding is row-independent, so the
+        padding changes no real row."""
         enc = self.encoder
-        o = np.asarray(
-            enc.embed_stars(
-                params,
-                np.asarray(stars.center_labels),
-                np.asarray(stars.leaf_labels),
-                np.asarray(stars.leaf_mask),
-            )
-        ).astype(np.float32)
-        o0 = np.asarray(enc.embed_isolated(params, np.asarray(stars.center_labels))).astype(
-            np.float32
+        n = center_labels.shape[0]
+        m = pow2_at_least(n)
+        c = pad_rows(center_labels, m)
+        o = enc.embed_stars(params, c, pad_rows(leaf_labels, m), pad_rows(leaf_mask, m))
+        o = np.asarray(o).astype(np.float32)[:n]
+        if not isolated:
+            return o, None
+        return o, np.asarray(enc.embed_isolated(params, c)).astype(np.float32)[:n]
+
+    def _node_embeddings(self, g, vset, stars, params, fallback_vertices, isolated=True):
+        """Embed every vertex of the expanded set; all-ones for overflow/fallback.
+        The isolated-vertex table is None unless ``isolated``."""
+        cfg = self.cfg
+        o, o0 = self._embed_star_rows(
+            params, stars.center_labels, stars.leaf_labels, stars.leaf_mask, isolated
         )
         # paper: high-degree → all-ones; ours: unverified vertices too
         o[stars.overflow] = 1.0
         if len(fallback_vertices):
             o[np.asarray(fallback_vertices, dtype=np.int64)] = 1.0
         node_emb = np.zeros((g.n_vertices, cfg.emb_dim), np.float32)
-        node_emb0 = np.zeros((g.n_vertices, cfg.emb_dim), np.float32)
         node_emb[vset] = o
+        if o0 is None:
+            return node_emb, None
+        node_emb0 = np.zeros((g.n_vertices, cfg.emb_dim), np.float32)
         node_emb0[vset] = o0
         return node_emb, node_emb0
 
@@ -566,48 +578,26 @@ class GnnPeEngine:
         rebuild over the updated graph would compute (the delta-vs-rebuild
         equivalence rests on this; see tests/test_delta_updates.py).
 
-        The star batch pads to a power-of-two bucket (repeating the first
-        vertex) so the jitted encoder sees a handful of recurring shapes
-        instead of retracing on every touched-set size — without this,
-        XLA recompilation dominates the whole update path."""
+        The star batch pads to a power-of-two bucket (``_embed_star_rows``):
+        without it, XLA recompilation dominates the whole update path."""
         g = self.graph
         cfg = self.cfg
-        enc = self.encoder
-        n = vids.size
-        n_pad = 8
-        while n_pad < n:
-            n_pad *= 2
-        pad_vids = (
-            np.concatenate([vids, np.full(n_pad - n, vids[0], np.int64)])
-            if n_pad != n
-            else vids
+        stars = build_star_tensors(g, vids, cfg.theta)
+        o, o0 = self._embed_star_rows(
+            model.params, stars.center_labels, stars.leaf_labels, stars.leaf_mask
         )
-        stars = build_star_tensors(g, pad_vids, cfg.theta)
-        o = np.asarray(
-            enc.embed_stars(
-                model.params,
-                np.asarray(stars.center_labels),
-                np.asarray(stars.leaf_labels),
-                np.asarray(stars.leaf_mask),
-            )
-        ).astype(np.float32)[:n]
-        o0 = np.asarray(enc.embed_isolated(model.params, np.asarray(stars.center_labels))).astype(
-            np.float32
-        )[:n]
-        overflow = stars.overflow[:n]
-        o[overflow] = 1.0
+        o[stars.overflow] = 1.0
         o[np.isin(vids, model.fallback_vids)] = 1.0
         model.node_emb[vids] = o
         model.node_emb0[vids] = o0
         for i in range(cfg.n_multi):
-            relab_c = self.label_perms[i][g.labels[pad_vids]].astype(np.int32)
+            relab_c = self.label_perms[i][g.labels[vids]].astype(np.int32)
             relab_l = self._relabel_leaves(stars.leaf_labels, stars.leaf_mask, i)
-            oi = np.asarray(
-                enc.embed_stars(
-                    model.multi_params[i], relab_c, np.asarray(relab_l), np.asarray(stars.leaf_mask)
-                )
-            ).astype(np.float32)[:n]
-            oi[overflow] = 1.0
+            oi, _ = self._embed_star_rows(
+                model.multi_params[i], relab_c, np.asarray(relab_l), stars.leaf_mask,
+                isolated=False,
+            )
+            oi[stars.overflow] = 1.0
             oi[np.isin(vids, model.fallback_vids_multi[i])] = 1.0
             model.node_emb_multi[i][vids] = oi
 
@@ -849,7 +839,9 @@ class GnnPeEngine:
                 leaf_labels=self._relabel_leaves(stars.leaf_labels, stars.leaf_mask, i),
             )
             fb_i = np.nonzero(np.isin(vset, model.fallback_vids_multi[i]))[0]
-            emb_i, _ = self._node_embeddings(g, vset, stars_i, model.multi_params[i], fb_i)
+            emb_i, _ = self._node_embeddings(
+                g, vset, stars_i, model.multi_params[i], fb_i, isolated=False
+            )
             node_emb_multi[i] = emb_i
         paths = enumerate_paths(g, members, cfg.path_length)
         emb = concat_path_embeddings(paths, node_emb)

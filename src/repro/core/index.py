@@ -66,6 +66,15 @@ __all__ = [
 # incremented on every fused Pallas leaf scan — lets integration tests prove
 # the kernel runs on the engine's real query path (not just in kernel tests)
 PALLAS_SCAN_CALLS = 0
+# the most (query, row/group) pairs one such call has checked — shows a
+# scan spanned more than one kernel block
+PALLAS_SCAN_MAX_PAIRS = 0
+
+
+def _count_pallas_scan(n_pairs: int) -> None:
+    global PALLAS_SCAN_CALLS, PALLAS_SCAN_MAX_PAIRS
+    PALLAS_SCAN_CALLS += 1
+    PALLAS_SCAN_MAX_PAIRS = max(PALLAS_SCAN_MAX_PAIRS, int(n_pairs))
 
 # (query, row) / (query, group) pairs issued by the batched probes since the
 # last reset — benchmarks/CI use these to prove the two-level grouped probe
@@ -596,8 +605,7 @@ def _pairs_keep_mask(qg, q0g, eg, e0g, eps: float, use_pallas: bool) -> np.ndarr
     if use_pallas:
         from ..kernels.dominance_scan.ops import dominance_scan_pairs
 
-        global PALLAS_SCAN_CALLS
-        PALLAS_SCAN_CALLS += 1
+        _count_pallas_scan(qg.shape[0])
         return np.asarray(dominance_scan_pairs(qg, q0g, eg, e0g, eps=eps)).astype(bool)
     # NumPy reference (bit-equal): one row-aligned compare-reduce
     keep = np.all(qg <= eg + eps, axis=1)
@@ -692,8 +700,7 @@ def _groups_keep_mask(qg, q0g, hi, lo0, hi0, eps: float, use_pallas: bool) -> np
     if use_pallas:
         from ..kernels.dominance_scan.ops import dominance_scan_groups
 
-        global PALLAS_SCAN_CALLS
-        PALLAS_SCAN_CALLS += 1
+        _count_pallas_scan(qg.shape[0])
         return np.asarray(dominance_scan_groups(qg, q0g, hi, lo0, hi0, eps=eps)).astype(bool)
     keep = np.all(qg <= hi + eps, axis=1)
     keep &= np.all(q0g <= hi0 + eps, axis=1)

@@ -45,6 +45,7 @@ from ..kernels.merge_join.ops import (
     pack_words,
     run_lookup,
 )
+from ..shapes import pow2_at_least
 
 __all__ = [
     "join_candidates",
@@ -384,13 +385,6 @@ def match_from_candidates(
 # --------------------------------------------------------------------------
 
 
-def _pow2(n: int, floor: int = 16) -> int:
-    out = floor
-    while out < n:
-        out *= 2
-    return out
-
-
 def _key_bits(n_values: int) -> int:
     """Bits per id column, covering the two pad sentinels too."""
     return max(int(np.ceil(np.log2(n_values + 2))), 1)
@@ -466,7 +460,7 @@ def _init_body(cand, count, *, bits: int, n_values: int, dedup: bool):
     return _settle(cand, valid & ok, bits, n_values, dedup=dedup)
 
 
-def _bounds_body(table, cand, count_c, *, t_idx, c_idx, bits: int, n_values: int):
+def _bounds_body(table, cand, count_c, t_idx, c_idx, *, bits: int, n_values: int):
     """Group the candidate side by its shared-column key and locate every
     table row's run of equal keys (the sort-merge core).  Sentinel table
     rows (id ``n_values``) never meet sentinel candidate rows
@@ -479,52 +473,53 @@ def _bounds_body(table, cand, count_c, *, t_idx, c_idx, bits: int, n_values: int
     packed-word sort + ``run_lookup`` search path.
     """
     cand = jnp.where((jnp.arange(cand.shape[0]) < count_c)[:, None], cand, n_values + 1)
-    if len(c_idx) == 1 and n_values + 2 <= 8 * cand.shape[0]:
+    if c_idx.shape[0] == 1 and n_values + 2 <= 8 * cand.shape[0]:
         # dense path only while the per-vertex run table is comparable to
         # the candidate bucket itself — on huge graphs with small
         # candidate sets the O(n_vertices) bincount+cumsum would dwarf
         # the join, so those take the packed-key search below
-        ckey = cand[:, c_idx[0]]
+        ckey = jnp.take(cand, c_idx[0], axis=1)
         order_c = jnp.argsort(ckey, stable=True)
         counts = jnp.zeros(n_values + 2, jnp.int32).at[ckey].add(1)
         starts = jnp.cumsum(counts) - counts
-        tkey = table[:, t_idx[0]]
+        tkey = jnp.take(table, t_idx[0], axis=1)
         lo = starts[tkey]
         hi = lo + counts[tkey]
     else:
-        ck = pack_words(cand[:, list(c_idx)], bits)
+        ck = pack_words(jnp.take(cand, c_idx, axis=1), bits)
         order_c = lex_order(ck)
-        lo, hi = run_lookup(ck[order_c], pack_words(table[:, list(t_idx)], bits))
+        lo, hi = run_lookup(ck[order_c], pack_words(jnp.take(table, t_idx, axis=1), bits))
     return cand[order_c], lo, hi, jnp.sum(hi - lo)
 
 
-def _merge_body(table, cand_s, lo, hi, *, cap: int, n_idx, bits: int, n_values: int, dedup: bool):
+def _merge_body(table, cand_s, lo, hi, n_idx, *, cap: int, bits: int, n_values: int, dedup: bool):
     """Run-length pair expansion → merged rows → injectivity → settle."""
     r, c, valid = expand_pairs(lo, hi, cap)
     old_w = table.shape[1]
-    merged = jnp.concatenate([table[r], cand_s[c][:, list(n_idx)]], axis=1)
-    if n_idx:
+    merged = jnp.concatenate([table[r], jnp.take(cand_s[c], n_idx, axis=1)], axis=1)
+    if n_idx.shape[0]:
         valid &= injectivity_mask(merged[:, :old_w], merged[:, old_w:])
     return _settle(merged, valid, bits, n_values, dedup=dedup)
 
 
 def _joinstep_body(
-    table, cand, count_c, *, cap: int, t_idx, c_idx, n_idx, bits: int,
-    n_values: int, dedup: bool,
+    table, cand, count_c, t_idx, c_idx, n_idx, *, cap: int, bits: int, n_values: int,
+    dedup: bool,
 ):
     """Bounds + merge fused into ONE program: the grouped candidate side,
     run bounds, pair expansion, injectivity and settle never materialize
-    between dispatches.  ``cap`` is a guessed pair bucket — the returned
-    ``total`` lets the driver detect a too-small guess (truncated
+    between dispatches.  The column indices are operands, not constants:
+    steps of different plans that differ only in which columns they key
+    on share one compiled program.  ``cap`` is a guessed pair bucket — the
+    returned ``total`` lets the host loop detect a too-small guess (truncated
     expansion) and re-run once with the exact power-of-two; guesses
     come from the previous execution of the same step signature, so a
     warm serving loop never retries."""
     cand_s, lo, hi, total = _bounds_body(
-        table, cand, count_c, t_idx=t_idx, c_idx=c_idx, bits=bits, n_values=n_values
+        table, cand, count_c, t_idx, c_idx, bits=bits, n_values=n_values
     )
     merged, valid, count = _merge_body(
-        table, cand_s, lo, hi, cap=cap, n_idx=n_idx, bits=bits,
-        n_values=n_values, dedup=dedup,
+        table, cand_s, lo, hi, n_idx, cap=cap, bits=bits, n_values=n_values, dedup=dedup,
     )
     return merged, valid, count, total
 
@@ -590,20 +585,17 @@ def _refine_body(
 
 _STEP_BODY = {
     "init": _init_body,
-    "bounds": _bounds_body,
-    "merge": _merge_body,
     "joinstep": _joinstep_body,
     "cartesian": _cartesian_body,
     "compact": _compact_body,
     "refine": _refine_body,
 }
 # vmap axes per body: batched tensors lead with the query axis; shared
-# graph tensors (refine's CSR + labels) map with in_axes=None
+# graph tensors (refine's CSR + labels) and a join step's column indices
+# map with in_axes=None
 _STEP_AXES = {
     "init": (0, 0),
-    "bounds": (0, 0, 0),
-    "merge": (0, 0, 0, 0),
-    "joinstep": (0, 0, 0),
+    "joinstep": (0, 0, 0, None, None, None),
     "cartesian": (0, 0, 0, 0),
     "compact": (0, 0),
     "refine": (0, 0, 0, 0, 0, 0, 0, 0, None, None),
@@ -623,12 +615,14 @@ def _join_mesh():
     ``jit(vmap(...))``."""
     global _JOIN_MESH
     if _JOIN_MESH is None:
-        from ..dist import compat  # grafts jax.shard_map on 0.4.x
-
-        compat.install()
-        n_dev = len(jax.devices())
+        devices = jax.local_devices()
         _JOIN_MESH = (
-            jax.make_mesh((n_dev,), ("join",)) if n_dev > 1 else False
+            jax.make_mesh(
+                (len(devices),), ("join",), devices=devices,
+                axis_types=(jax.sharding.AxisType.Auto,),
+            )
+            if len(devices) > 1
+            else False
         )
     return _JOIN_MESH or None
 
@@ -701,7 +695,7 @@ def _join_candidates_device_batch(
     cnt = np.asarray([[c[1] for c in grp] for grp in cand_groups], np.int64)  # (B, P)
     order = np.argsort(cnt.mean(axis=0), kind="stable")
     first = int(order[0])
-    cap0 = _pow2(int(cnt[:, first].max()))
+    cap0 = pow2_at_least(int(cnt[:, first].max()), 16)
     stack0 = _stack_candidates(
         [grp[first][0] for grp in cand_groups], cnt[:, first], cap0,
         len(plan_paths[first]),
@@ -727,23 +721,23 @@ def _join_candidates_device_batch(
         t_idx = tuple(cols.index(c) for c in shared)
         c_idx = tuple(cand_cols.index(c) for c in shared)
         n_idx = tuple(cand_cols.index(c) for c in new_cols)
-        capc = _pow2(int(cnt[:, nxt].max()))
+        capc = pow2_at_least(int(cnt[:, nxt].max()), 16)
         cstack = _stack_candidates(
             [grp[nxt][0] for grp in cand_groups], cnt[:, nxt], capc, len(cand_cols)
         )
         ccounts = jnp.asarray(cnt[:, nxt].astype(np.int32))
         if shared:
             guess_key = (n_values, t_idx, c_idx, n_idx, tables.shape[1:], cstack.shape[1:])
-            cap = _pow2(_CAP_GUESS.get(guess_key, cstack.shape[1]))
+            cap = pow2_at_least(_CAP_GUESS.get(guess_key, cstack.shape[1]), 16)
+            step_idx = [jnp.asarray(ix, jnp.int32) for ix in (t_idx, c_idx, n_idx)]
             for _ in range(2):  # second pass only on a cold/overflowed guess
                 tables2, valids2, counts_dev, totals = _step_fn(
-                    "joinstep", cap=cap, t_idx=t_idx, c_idx=c_idx, n_idx=n_idx,
-                    bits=bits, n_values=n_values, dedup=dedup,
-                )(tables, cstack, ccounts)
+                    "joinstep", cap=cap, bits=bits, n_values=n_values, dedup=dedup,
+                )(tables, cstack, ccounts, *step_idx)
                 tmax = int(np.asarray(totals).max())
                 if tmax <= cap:
                     break
-                cap = _pow2(tmax)
+                cap = pow2_at_least(tmax, 16)
             _CAP_GUESS[guess_key] = tmax
             if len(_CAP_GUESS) > 4096:
                 _CAP_GUESS.pop(next(iter(_CAP_GUESS)))
@@ -769,7 +763,7 @@ def _join_candidates_device_batch(
     # row counts from here on, not the last pair bucket
     tables, counts_dev = _step_fn("compact", n_values=n_values)(tables, valids)
     counts = np.asarray(counts_dev).astype(np.int64)
-    tables = tables[:, : _pow2(int(max(counts.max(), 1)))]
+    tables = tables[:, : pow2_at_least(int(max(counts.max(), 1)), 16)]
     return tables, counts[:B], cols
 
 
@@ -920,14 +914,14 @@ def _refine_device_batch(
         B = b_pad
     inv = jnp.asarray(np.ascontiguousarray(colperms).astype(np.int32))
     variant, ops, deg_steps, labels = _edge_tensors_device(g)
-    e_cap = _pow2(max(e.shape[0] for e in edges), floor=4)
+    e_cap = pow2_at_least(max(e.shape[0] for e in edges), 4)
     qe = np.zeros((B, e_cap, 2), np.int32)
     n_qe = np.zeros(B, np.int32)
     for b, e in enumerate(edges):
         qe[b, : e.shape[0]] = e
         n_qe[b] = e.shape[0]
     n_max = max(x.shape[0] for x in non_edges)
-    n_cap = _pow2(n_max, floor=4) if n_max else 0
+    n_cap = pow2_at_least(n_max, 4) if n_max else 0
     qnon = np.zeros((B, n_cap, 2), np.int32)
     n_qn = np.zeros(B, np.int32)
     for b, x in enumerate(non_edges):

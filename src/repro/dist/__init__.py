@@ -1,11 +1,2 @@
 """Distributed substrate: sharding specs, mesh context, checkpointing,
-pipeline parallelism.
-
-Importing any ``repro.dist`` submodule installs a small compatibility
-shim (`compat.install`) so code written against newer jax mesh APIs
-(``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``)
-also runs on the jax pinned in this container.
-"""
-from . import compat as _compat
-
-_compat.install()
+pipeline parallelism, the stacked partition probe and the cluster tier."""

@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_apply"]
@@ -53,7 +52,7 @@ def pipeline_apply(stage_fn, stage_params, xs, mesh, axis: str = "pipe"):
         return outs[None]  # (1, M, B, D) per device → (P, M, B, D) global
 
     w_specs = jax.tree.map(lambda _: P(axis), stage_params)
-    f = shard_map(
-        local, mesh=mesh, in_specs=(w_specs, P()), out_specs=P(axis), check_rep=False
+    f = jax.shard_map(
+        local, mesh=mesh, in_specs=(w_specs, P()), out_specs=P(axis), check_vma=False
     )
     return f(stage_params, xs)[-1]

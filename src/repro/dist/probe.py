@@ -32,20 +32,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from ..core import index as index_mod
 from ..core.index import quantize_query
 from ..core.stacked import StackedIndex, build_stacked, restack_slot, stacked_masks_ref
+from ..shapes import pow2_at_least
 
 __all__ = ["StackedProbe"]
-
-
-def _pow2_at_least(n: int, floor: int = 8) -> int:
-    out = floor
-    while out < n:
-        out *= 2
-    return out
 
 
 class StackedProbe:
@@ -81,8 +75,14 @@ class StackedProbe:
         self.leaf_pair_cap = int(leaf_pair_cap)
         n_dev = max(len(self.devices), 1)
         self.stacked = stacked if stacked is not None else build_stacked(indexes, n_shards=n_dev)
+        # Auto axes: shardings stay out of the traced types, so the plain
+        # jits downstream (cell expansion, pair stage) accept these arrays
         self.mesh = (
-            jax.make_mesh((n_dev,), ("part",), devices=self.devices) if n_dev > 1 else None
+            jax.make_mesh(
+                (n_dev,), ("part",), devices=self.devices, axis_types=(AxisType.Auto,)
+            )
+            if n_dev > 1
+            else None
         )
         self._mask_fns: dict = {}
         # device-join support (probe_device): source indexes for the lazy
@@ -178,7 +178,7 @@ class StackedProbe:
     def _device_masks_dev(self, q_cat, q0, eps, use_groups):
         """(S, Q, Dcat/D0) query tensors → (alive, gkeep) DEVICE masks."""
         S, Q = q_cat.shape[:2]
-        Qp = _pow2_at_least(Q)
+        Qp = pow2_at_least(Q)
         if Qp != Q:  # bucket Q: padded queries carry +inf and never survive
             q_cat = np.concatenate(
                 [q_cat, np.full((S, Qp - Q, q_cat.shape[2]), np.inf, np.float32)], axis=1
@@ -593,7 +593,7 @@ class StackedProbe:
         g_start = leaf.get("g_start", dummy)
         g_count = leaf.get("g_count", dummy)
         if n_cells:
-            cell_cap = _pow2_at_least(n_cells, 16)
+            cell_cap = pow2_at_least(n_cells, 16)
             pi, qi, starts, counts, total_dev = self._cells_fn(use_groups, cell_cap)(
                 mask, n_cells, leaf["n_paths"], g_start, g_count
             )
@@ -625,7 +625,7 @@ class StackedProbe:
             per_b = [empty_b for _ in range(Q)]
             combo_counts = np.zeros(S * Q, np.int64)
         else:
-            pair_cap = _pow2_at_least(total, 16)
+            pair_cap = pow2_at_least(total, 16)
             quantized = leaf["emb_q"] is not None
             hashed = quantized and "lh_hi" in leaf and q_label_hash is not None
             qq = (
@@ -648,8 +648,13 @@ class StackedProbe:
             counts_b = np.asarray(counts_b)
             combo_counts = np.asarray(combo_counts)
             offs = np.concatenate([[0], np.cumsum(counts_b)])
+            # each query's rows are a power-of-two-long slice (the join
+            # reads only ``count`` of them), so the eager slices compile a
+            # handful of shapes, not one per candidate count
+            lens = [pow2_at_least(int(c), 16) for c in counts_b]
+            verts_p = jnp.pad(verts_s, ((0, max(lens)), (0, 0)))
             per_b = [
-                (verts_s[int(offs[b]) : int(offs[b]) + int(counts_b[b])], int(counts_b[b]))
+                (verts_p[int(offs[b]) : int(offs[b]) + lens[b]], int(counts_b[b]))
                 for b in range(Q)
             ]
         cc = combo_counts.reshape(S, Q)

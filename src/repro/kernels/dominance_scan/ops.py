@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...shapes import lanes, pow2_at_least
 from .kernel import (
     dominance_scan_batch_pallas,
     dominance_scan_pairs_pallas,
@@ -46,11 +47,34 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _pow2_at_least(n: int, floor: int) -> int:
-    out = floor
-    while out < n:
-        out *= 2
-    return out
+# Padded shapes and block sizes each wrapper hands its kernel (the TPU
+# compile tests lower the kernels at exactly these).  On a TPU a multi-
+# block grid needs blocks of 8 × 128 rows: the per-row verdicts leave as
+# lane-dense (block // 128, 128) int32 tiles (see kernel.py).
+
+
+def scan_layout(N: int, D: int, D0: int, block_n: int, interpret: bool):
+    """(Np, Dp, D0p, block_n) for the single-query scan."""
+    if not interpret:
+        block_n = int(np.ceil(block_n / 1024) * 1024)
+    return int(np.ceil(N / block_n) * block_n), lanes(D), lanes(D0), block_n
+
+
+def batch_layout(Q: int, N: int, D: int, D0: int, block_q: int, block_n: int):
+    """(Qp, Np, Dp, D0p) for the batched scan: Q and N bucket to powers of
+    two, so the jit cache stays small over ragged candidate sets."""
+    return pow2_at_least(Q, block_q), pow2_at_least(N, block_n), lanes(D), lanes(D0)
+
+
+def pairs_layout(T: int, D: int, D0: int, block_t: int, interpret: bool):
+    """(Tp, Dp, D0p, block_t) for the pair scan.  T buckets to a power of
+    two.  Interpret mode pays per-grid-step emulation overhead, not VMEM
+    limits, so it takes one big unpadded tile; a TPU keeps the VMEM-sized
+    tile, padded to whole lanes."""
+    Tp = pow2_at_least(T, min(block_t, 256))
+    if interpret:
+        return Tp, D, D0, min(Tp, 1 << 16)
+    return Tp, lanes(D), lanes(D0), min(max(block_t, 1024), Tp)
 
 
 def dominance_scan(
@@ -79,9 +103,7 @@ def dominance_scan(
     if N == 0:
         return jnp.zeros((0,), jnp.int32)
     interpret = (not _on_tpu()) if interpret is None else interpret
-    Dp = int(np.ceil(D / 128) * 128)
-    D0p = int(np.ceil(D0 / 128) * 128)
-    Np = int(np.ceil(N / block_n) * block_n)
+    Np, Dp, D0p, block_n = scan_layout(N, D, D0, block_n, interpret)
     # pad features with zeros: q_pad=0 <= emb_pad=0 and |0-0|<=eps → neutral
     qp = jnp.pad(q, (0, Dp - D))
     q0p = jnp.pad(q0, (0, D0p - D0))
@@ -121,12 +143,7 @@ def dominance_scan_batch(
     if not use_pallas:
         return dominance_scan_batch_ref(q, q0, emb, emb0, eps)
     interpret = (not _on_tpu()) if interpret is None else interpret
-    Dp = int(np.ceil(D / 128) * 128)
-    D0p = int(np.ceil(D0 / 128) * 128)
-    # bucket Q and N to powers of two → bounded jit-cache growth over the
-    # ragged candidate-set sizes the engine produces
-    Qp = _pow2_at_least(Q, block_q)
-    Np = _pow2_at_least(N, block_n)
+    Qp, Np, Dp, D0p = batch_layout(Q, N, D, D0, block_q, block_n)
     qp = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, Dp - D)))
     qp = jnp.pad(qp, ((0, Qp - Q), (0, 0)), constant_values=jnp.inf)
     q0p = jnp.pad(q0.astype(jnp.float32), ((0, Qp - Q), (0, D0p - D0)))
@@ -164,12 +181,7 @@ def dominance_scan_pairs(
     if not use_pallas:
         return dominance_scan_pairs_ref(qg, q0g, eg, e0g, eps)
     interpret = (not _on_tpu()) if interpret is None else interpret
-    Dp = D if interpret else int(np.ceil(D / 128) * 128)
-    D0p = D0 if interpret else int(np.ceil(D0 / 128) * 128)
-    Tp = _pow2_at_least(T, min(block_t, 256))
-    # interpret mode pays per-grid-step emulation overhead, not VMEM limits:
-    # one big tile beats many small ones (real TPUs keep the VMEM-sized tile)
-    block_t = min(Tp, 1 << 16) if interpret else min(block_t, Tp)
+    Tp, Dp, D0p, block_t = pairs_layout(T, D, D0, block_t, interpret)
     qgp = jnp.pad(qg.astype(jnp.float32), ((0, 0), (0, Dp - D)))
     qgp = jnp.pad(qgp, ((0, Tp - T), (0, 0)), constant_values=jnp.inf)
     q0gp = jnp.pad(q0g.astype(jnp.float32), ((0, Tp - T), (0, D0p - D0)))

@@ -37,10 +37,8 @@ def flash_attention_kernel(
 
     def body(kj, carry):
         m, l, acc = carry
-        # leading dim indexed with a 1-slice (not a bare int: older pallas
-        # interpret mode can't discharge scalar int indices in pl.load)
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(kj * block_k, block_k), slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(kj * block_k, block_k), slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bk)
         k_pos = kj * block_k + jax.lax.iota(jnp.int32, block_k)
         mask = jnp.ones((block_q, block_k), bool)

@@ -11,8 +11,9 @@ is one row-aligned tile of int32 vertex ids, and the injectivity check
 
 is an elementwise compare-reduce with zero cross-row traffic — the same
 shape as the ``dominance_scan_pairs`` leaf verdict, so it streams through
-VMEM the same way: (block_t, C) tiles, one pass, the (block_t, Cn, Co)
-compare intermediate never leaves VMEM.
+VMEM the same way: (block_t, C) tiles, one pass, one (block_t, C)
+compare per live new column (a (block_t, Cn, Co) intermediate at the
+128-lane padded widths would be 128 MB, far past VMEM).
 
 Column counts are tiny (≤ query size), so ops.py pads the last dim with
 sentinels that cannot collide (old → −1, new column j → −(j+2)) rather
@@ -29,40 +30,45 @@ from jax.experimental import pallas as pl
 __all__ = ["injectivity_mask_kernel", "injectivity_mask_pallas"]
 
 
-def injectivity_mask_kernel(old_ref, new_ref, out_ref):
+def injectivity_mask_kernel(old_ref, new_ref, out_ref, *, n_new: int):
     old = old_ref[...]  # (block_t, Co) int32
     new = new_ref[...]  # (block_t, Cn) int32
-    collide = jnp.any(new[:, :, None] == old[:, None, :], axis=(1, 2))
-    # pairwise-distinct among the new columns: strict upper triangle only
-    cn = new.shape[1]
-    jj = jax.lax.broadcasted_iota(jnp.int32, (cn, cn), 0)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (cn, cn), 1)
-    dup = jnp.any(
-        (new[:, :, None] == new[:, None, :]) & (jj < kk)[None, :, :], axis=(1, 2)
-    )
-    out_ref[...] = (~collide & ~dup).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, new.shape, 1)
+    bad = jnp.zeros((new.shape[0],), bool)
+    # one (block_t, C) compare per live new column: collisions with the
+    # old columns, and duplicates among the new columns (j < j' only);
+    # the lane padding past ``n_new`` holds sentinels and is never a probe
+    for j in range(n_new):
+        col = new[:, j : j + 1]
+        bad |= jnp.any(col == old, axis=1)
+        bad |= jnp.any((col == new) & (lane > j), axis=1)
+    out_ref[...] = (~bad).astype(jnp.int32).reshape(out_ref.shape)
 
 
-@functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def injectivity_mask_pallas(old, new, *, block_t: int = 2048, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("n_new", "block_t", "interpret"))
+def injectivity_mask_pallas(
+    old, new, *, n_new: int | None = None, block_t: int = 1024, interpret: bool = True
+):
     """old (T, Co), new (T, Cn) int32 → (T,) int32 keep mask.
 
-    T must be a multiple of block_t (ops.py pads + buckets); padded rows
-    carry non-colliding sentinels and come back keep=1 — callers AND the
-    result with their validity mask.
+    ``n_new`` is the count of live new columns (default all ``Cn``); the
+    rest are lane padding.  T must be a multiple of block_t and block_t of
+    128 (ops.py pads + buckets); padded rows carry non-colliding sentinels
+    and come back keep=1 — callers AND the result with their validity
+    mask.  The verdicts leave lane-dense, as in ``dominance_scan``.
     """
     T, Co = old.shape
     Cn = new.shape[1]
-    assert T % block_t == 0, (T, block_t)
+    assert T % block_t == 0 and block_t % 128 == 0, (T, block_t)
     grid = (T // block_t,)
     return pl.pallas_call(
-        injectivity_mask_kernel,
+        functools.partial(injectivity_mask_kernel, n_new=Cn if n_new is None else n_new),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_t, Co), lambda i: (i, 0)),
             pl.BlockSpec((block_t, Cn), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_t,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((T,), jnp.int32),
+        out_specs=pl.BlockSpec((block_t // 128, 128), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((T // 128, 128), jnp.int32),
         interpret=interpret,
-    )(old, new)
+    )(old, new).reshape(T)

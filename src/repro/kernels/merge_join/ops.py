@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...shapes import lanes, pow2_at_least
 from .kernel import injectivity_mask_pallas
 from .ref import (
     dedup_mask_ref,
@@ -95,17 +96,23 @@ def _words_le(a, b):
     return out
 
 
+def _search_init(probe_words, n: int):
+    """Initial [lo, hi) = [0, n) per probe row, derived from the probe
+    operand so the binary-search carry varies over the same mesh axes
+    as the loop body's output (``shard_map`` checks carry types)."""
+    lo = jnp.zeros_like(probe_words[:, 0], dtype=jnp.int32)
+    return lo, jnp.full_like(lo, n)
+
+
 def run_bounds(sorted_words, probe_words):
     """For each probe key, the [lo, hi) run of equal keys in the sorted
     array — one vectorized binary search per side, ``ceil(log2 N)``
     fori steps of a K-word compare (no 64-bit scalar ever formed)."""
     n = sorted_words.shape[0]
-    m = probe_words.shape[0]
     steps = max(int(n).bit_length(), 1)
 
     def search(strict_less):
-        lo = jnp.zeros((m,), jnp.int32)
-        hi = jnp.full((m,), n, jnp.int32)
+        lo, hi = _search_init(probe_words, n)
 
         def body(_, lohi):
             lo, hi = lohi
@@ -132,10 +139,8 @@ def run_lookup(sorted_words, probe_words):
     over the key-change boundaries).  Preferred on backends where gathers
     dominate (every search step gathers (M, K) words)."""
     n = sorted_words.shape[0]
-    m = probe_words.shape[0]
     steps = max(int(n).bit_length(), 1)
-    lo = jnp.zeros((m,), jnp.int32)
-    hi = jnp.full((m,), n, jnp.int32)
+    lo, hi = _search_init(probe_words, n)
 
     def body(_, lohi):
         lo, hi = lohi
@@ -172,6 +177,19 @@ def expand_pairs(lo, hi, cap: int):
     return r, c, valid
 
 
+def injectivity_layout(T: int, co: int, cn: int, interpret: bool):
+    """(Tp, Co_p, Cn_p, block_t) the Pallas verdict runs at.  Output tiles
+    are lane-dense: ≥ 128 rows a block, and 8 × 128 whenever the grid has
+    more than one block; 1024 rows of 128-lane compares fit the 16 MB of
+    scoped VMEM (2048 do not).  The (tiny) column dims lane-pad on real
+    TPUs only."""
+    block_t = min(1024, pow2_at_least(T, 128))
+    Tp = ((T + block_t - 1) // block_t) * block_t
+    if interpret:
+        return Tp, co, cn, block_t
+    return Tp, lanes(co), lanes(cn), block_t
+
+
 def injectivity_mask(old, new, use_pallas: bool = False, interpret: bool | None = None):
     """Row-aligned injectivity verdict (see kernel.py): keep[t] iff row
     t's new columns collide with nothing.  ``use_pallas`` routes through
@@ -187,23 +205,21 @@ def injectivity_mask(old, new, use_pallas: bool = False, interpret: bool | None 
         return ok
     interpret = (jax.default_backend() != "tpu") if interpret is None else interpret
     T, cn = old.shape[0], new.shape[1]
-    block_t = min(2048, max(int(np.exp2(np.ceil(np.log2(max(T, 1))))), 8))
-    Tp = ((T + block_t - 1) // block_t) * block_t
+    Tp, co_p, cn_p, block_t = injectivity_layout(T, old.shape[1], cn, interpret)
     # sentinels never collide: old pads/lanes −1, new column j pads −(j+2)
     oldp = jnp.pad(old, ((0, Tp - T), (0, 0)), constant_values=-1)
     fill = jnp.broadcast_to(
         -(jnp.arange(cn, dtype=jnp.int32)[None, :] + 2), (Tp - T, cn)
     )
     newp = jnp.concatenate([new.astype(jnp.int32), fill], axis=0)
-    if not interpret:  # lane-pad the (tiny) column dims on real TPUs only
-        co_p = int(np.ceil(old.shape[1] / 128) * 128)
-        cn_p = int(np.ceil(cn / 128) * 128)
-        oldp = jnp.pad(oldp, ((0, 0), (0, co_p - old.shape[1])), constant_values=-1)
-        lane_fill = jnp.broadcast_to(
-            -(jnp.arange(cn, cn_p, dtype=jnp.int32)[None, :] + 2), (Tp, cn_p - cn)
-        )
-        newp = jnp.concatenate([newp, lane_fill], axis=1)
-    mask = injectivity_mask_pallas(oldp, newp, block_t=block_t, interpret=interpret)
+    oldp = jnp.pad(oldp, ((0, 0), (0, co_p - old.shape[1])), constant_values=-1)
+    lane_fill = jnp.broadcast_to(
+        -(jnp.arange(cn, cn_p, dtype=jnp.int32)[None, :] + 2), (Tp, cn_p - cn)
+    )
+    newp = jnp.concatenate([newp, lane_fill], axis=1)
+    mask = injectivity_mask_pallas(
+        oldp, newp, n_new=cn, block_t=block_t, interpret=interpret
+    )
     return mask[:T].astype(bool)
 
 

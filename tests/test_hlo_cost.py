@@ -62,7 +62,6 @@ def test_collectives_counted_with_loop_multiplier():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
-        import repro.dist  # installs AxisType/make_mesh compat on older jax
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.hlo_cost import analyze_hlo
 

@@ -220,6 +220,16 @@ def test_stacked_probe_shard_map_4dev():
             for i in range(len(indexes)):
                 for qi in range(6):
                     np.testing.assert_array_equal(ref[i][qi], got[i][qi])
+            # device-resident assembly over the same mesh: the candidate
+            # path vertices of every partition, in slot order
+            per_b, _ = probe.probe_device(
+                q_emb, q_emb0, q_multi, q_label_hash=qh,
+                use_groups=use_groups, use_pallas=False,
+            )
+            for qi, (verts, n) in enumerate(per_b):
+                want = [tuple(p) for i, ix in enumerate(indexes) for p in ix.paths[ref[i][qi]]]
+                got_v = [tuple(p) for p in np.asarray(verts)[:n]]
+                assert sorted(got_v) == sorted(want), (use_groups, qi)
         print("STACKED_SHARD_OK")
         """
     )
