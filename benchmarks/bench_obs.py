@@ -2,8 +2,7 @@
 
 The obs layer's contract is that it is cheap enough to leave on in
 production: module-flag-guarded counters, one small lock per metric
-child, and spans (plus the grouped-probe traversal stats that feed the
-surviving-groups funnel rung) only materialised for *sampled* traces.
+child, and spans only materialised for *sampled* traces.
 This bench proves it on a bench_serving-style stream — a
 ``MatchServer`` tick loop draining query batches, with one update
 epoch landing between measured passes — over identical engine replicas
@@ -13,8 +12,8 @@ epoch landing between measured passes — over identical engine replicas
 * **sampled** — metrics on, ``trace_rate=0.25`` (the production
   shape: every request counted, a quarter fully traced) — THE GATED
   ARM (``overhead_under_5pct``);
-* **full** — metrics on, ``trace_rate=1.0``: every tick traced, every
-  probe collecting traversal stats.  Reported ungated
+* **full** — metrics on, ``trace_rate=1.0``: every tick traced.
+  Reported ungated
   (``overhead_pct_full_trace``) — it is the knowingly-paid debug mode
   and documents exactly what sampling buys.
 
@@ -117,8 +116,7 @@ def run(full: bool = False, json_path: str | None = None) -> dict:
     old_rate = TRACER.trace_rate
     try:
         # warm every replica (JIT compile + first-touch) outside the
-        # clock, each in the mode it will be measured in (the traced
-        # probe requests traversal stats — its own compiled variant)
+        # clock, each in the mode it will be measured in
         for arm, srv in servers.items():
             traced = _arm(arm)
             _pass(srv, stream, traced)

@@ -75,17 +75,13 @@ __all__ = ["GnnPeConfig", "PartitionModel", "GnnPeEngine", "QueryStats"]
 # eviction keeps a long-lived MatchServer from growing without limit
 _PLAN_CACHE_MAX = 4096
 
-# engine-level registry metrics (repro.obs): batch latency, per-stage
-# seconds, result-cache lookup outcomes, and the pruning funnel — the
-# process-wide cumulative complement to the per-query trace funnel
+# engine-level registry metrics (repro.obs): batch latency, result-cache
+# lookup outcomes, and the pruning funnel — the process-wide cumulative
+# complement to the per-query trace funnel (per-stage and per-step
+# seconds are obs.trace.step's)
 _M_QUERIES = _OBS.counter("gnnpe_engine_queries_total", "Queries matched via match_many")
 _M_BATCH_S = _OBS.histogram(
     "gnnpe_engine_match_batch_seconds", "Wall seconds per match_many call"
-)
-_M_STAGE_S = _OBS.histogram(
-    "gnnpe_engine_stage_seconds",
-    "Wall seconds per fused pipeline stage",
-    labels=("stage",),
 )
 _M_RCACHE = _OBS.counter(
     "gnnpe_result_cache_lookups_total",
@@ -1404,32 +1400,44 @@ class GnnPeEngine:
         """
         cfg = self.cfg
         enc = self.encoder
-        star_list = [build_star_tensors(q, np.arange(q.n_vertices), cfg.theta) for q in queries]
-        sizes = [q.n_vertices for q in queries]
-        spans = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
-        centers = np.concatenate([s.center_labels for s in star_list])
-        leaf_labels = np.concatenate([s.leaf_labels for s in star_list])
-        leaf_mask = np.concatenate([s.leaf_mask for s in star_list])
-        overflow = np.concatenate([s.overflow for s in star_list])
-        if not self.models:
-            return [], spans
+        with obs_trace.step("embed", "stars"):
+            star_list = [
+                build_star_tensors(q, np.arange(q.n_vertices), cfg.theta) for q in queries
+            ]
+            sizes = [q.n_vertices for q in queries]
+            spans = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
+            centers = np.concatenate([s.center_labels for s in star_list])
+            leaf_labels = np.concatenate([s.leaf_labels for s in star_list])
+            leaf_mask = np.concatenate([s.leaf_mask for s in star_list])
+            overflow = np.concatenate([s.overflow for s in star_list])
+            if not self.models:
+                return [], spans
+            relabeled = [
+                (
+                    self.label_perms[i][centers].astype(np.int32),
+                    self._relabel_leaves(leaf_labels, leaf_mask, i),
+                )
+                for i in range(cfg.n_multi)
+            ]
         main, multi = self._stacked_model_params()
-        o_all = np.asarray(
-            jax.vmap(lambda p: enc.embed_stars(p, centers, leaf_labels, leaf_mask))(main)
-        ).astype(np.float32)  # (m, n, d)
-        o0_all = np.asarray(
-            jax.vmap(lambda p: enc.embed_isolated(p, centers))(main)
-        ).astype(np.float32)
+        # every encoder call dispatches before the first result is read
+        with obs_trace.step("embed", "encode"):
+            outs = [
+                jax.vmap(lambda p: enc.embed_stars(p, centers, leaf_labels, leaf_mask))(main),
+                jax.vmap(lambda p: enc.embed_isolated(p, centers))(main),
+            ] + [
+                jax.vmap(lambda p, c=c, l=l: enc.embed_stars(p, c, l, leaf_mask))(multi[i])
+                for i, (c, l) in enumerate(relabeled)
+            ]
+        with obs_trace.wait("embed"):
+            outs = jax.device_get(outs)
+        o_all = outs[0].astype(np.float32)  # (m, n, d)
+        o0_all = outs[1].astype(np.float32)
         o_all[:, overflow] = 0.0
         om_all = np.zeros((cfg.n_multi, len(self.models), centers.shape[0], cfg.emb_dim), np.float32)
         for i in range(cfg.n_multi):
-            relab_c = self.label_perms[i][centers].astype(np.int32)
-            relab_l = self._relabel_leaves(leaf_labels, leaf_mask, i)
-            oi = np.asarray(
-                jax.vmap(lambda p: enc.embed_stars(p, relab_c, relab_l, leaf_mask))(multi[i])
-            ).astype(np.float32)
-            oi[:, overflow] = 0.0
-            om_all[i] = oi
+            om_all[i] = outs[2 + i]
+            om_all[i][:, overflow] = 0.0
         cat = [
             (o_all[mi], o0_all[mi], om_all[:, mi]) for mi in range(len(self.models))
         ]
@@ -1568,12 +1576,14 @@ class GnnPeEngine:
                 sel, gidx, qh = layouts[L]
                 B = len(sel)
                 mis = part_list
-                per_part = [query_tensors(mi, gidx, B) for mi in mis]
-                q_emb = np.stack([t[0] for t in per_part])
-                q_emb0 = np.stack([t[1] for t in per_part])
-                q_multi = (
-                    np.stack([t[2] for t in per_part], axis=1) if cfg.n_multi else None
-                )
+                with obs_trace.step("probe", "prepare"):
+                    per_part = [query_tensors(mi, gidx, B) for mi in mis]
+                    q_emb = np.stack([t[0] for t in per_part])
+                    q_emb0 = np.stack([t[1] for t in per_part])
+                    q_multi = (
+                        np.stack([t[2] for t in per_part], axis=1) if cfg.n_multi else None
+                    )
+                    live_mask = self._stacked_live_mask(probe) if use_dev else None
                 lp_before = probe.part_leaf_pairs.copy()
                 if use_dev:
                     # §device join: candidate vertices assemble on device,
@@ -1583,19 +1593,20 @@ class GnnPeEngine:
                         q_emb, q_emb0, q_multi, q_label_hash=qh,
                         use_groups=use_groups, use_pallas=use_pallas,
                         return_stats=stats_memo is not None,
-                        live_mask=self._stacked_live_mask(probe),
+                        live_mask=live_mask,
                     )
                     if stats_memo is not None:
                         per_b, part_counts, stats = out
                     else:
                         per_b, part_counts = out
-                    for b, (qi, p) in enumerate(sel):
-                        dev_memo[(qi, p)] = per_b[b]
-                        for mi in mis:
-                            dev_counts[(mi, qi, p)] = int(part_counts[mi, b])
-                            if stats_memo is not None:
-                                stats_memo[(mi, qi, p)] = stats[mi][b]
-                    self._part_probe_rows += part_counts.sum(axis=1)
+                    with obs_trace.step("probe", "account"):
+                        for b, (qi, p) in enumerate(sel):
+                            dev_memo[(qi, p)] = per_b[b]
+                            for mi in mis:
+                                dev_counts[(mi, qi, p)] = int(part_counts[mi, b])
+                                if stats_memo is not None:
+                                    stats_memo[(mi, qi, p)] = stats[mi][b]
+                        self._part_probe_rows += part_counts.sum(axis=1)
                 else:
                     out = probe.probe(
                         q_emb, q_emb0, q_multi, q_label_hash=qh,
@@ -1844,18 +1855,23 @@ class GnnPeEngine:
         plus per-partition counts (``dev_counts``) — the join consumes
         them without a host round-trip; delta-buffer rows (small by
         construction) upload alongside.
+
+        Each stage opens through ``obs.trace.step`` (stage histogram,
+        ``gnnpe.<stage>`` profiler annotation, span when traced).
         """
         cfg = self.cfg
         use_groups = kind == "grouped"
         nq = len(queries)
         stats = [QueryStats() for _ in range(nq)]
         trace = obs_trace.current_trace()
-        pairs_before = (index_mod._GROUP_PAIRS.value, index_mod._LEAF_PAIRS.value)
+        pairs_before = (
+            index_mod._GROUP_PAIRS.value,
+            index_mod._SURVIVING_GROUPS.value,
+            index_mod._LEAF_PAIRS.value,
+        )
         t0 = time.perf_counter()
-        with obs_trace.span("embed", n_queries=nq):
+        with obs_trace.step("embed", n_queries=nq):
             q_embs = self._query_node_embeddings_many(queries)
-        t_embed = time.perf_counter()
-        _M_STAGE_S.labels(stage="embed").observe(t_embed - t0)
         memo: dict = {}
         delta_memo: dict = {}
         delta = self.delta
@@ -1864,98 +1880,93 @@ class GnnPeEngine:
         dev_memo: dict | None = {} if device_assembly else None
         dev_counts: dict = {}
         # ---- plans (dr probes ride the same batched pipeline) -----------
-        plan_span_cm = obs_trace.span("plan", n_queries=nq)
-        plan_span = plan_span_cm.__enter__()
-        weight_fns: list = [None] * nq
-        cached_plans: list = [None] * nq
-        plan_group_size = 1
-        stats_memo: dict | None = None
-        if cfg.plan_weight == "dr":
-            if use_groups:
-                plan_group_size = cfg.group_size
-            cached_plans = [self._dr_plan_peek(q, plan_group_size) for q in queries]
-            probe_reqs = [
-                (qi, p)
+        with obs_trace.step("plan", n_queries=nq) as plan_span:
+            weight_fns: list = [None] * nq
+            cached_plans: list = [None] * nq
+            plan_group_size = 1
+            if cfg.plan_weight == "dr":
+                if use_groups:
+                    plan_group_size = cfg.group_size
+                cached_plans = [self._dr_plan_peek(q, plan_group_size) for q in queries]
+                probe_reqs = [
+                    (qi, p)
+                    for qi, q in enumerate(queries)
+                    if cached_plans[qi] is None
+                    for p in candidate_plan_paths(q, cfg.path_length)
+                ]
+                stats_memo = {} if use_groups else None
+                if probe_reqs:
+                    self._probe_batch(
+                        probe_reqs, queries, q_embs, memo,
+                        use_groups=use_groups, stats_memo=stats_memo, probe_impl=impl,
+                        delta_memo=delta_memo, dev_memo=dev_memo, dev_counts=dev_counts,
+                    )
+
+                def _delta_rows(mi, qi, p):
+                    rows = delta_memo.get((mi, qi, p))
+                    return rows.size if rows is not None else 0
+
+                if use_groups:
+                    # grouped cost model: weights are group fan-outs
+                    # (surviving groups — the probe's unit of leaf work)
+                    # instead of the per-path |DR(o(p_q))| counts the
+                    # two-level probe avoids materializing; plan_query's
+                    # group_size scale only converts the reported cost to
+                    # leaf-row units (selection is scale-invariant).  Delta
+                    # buffer rows count as ceil(rows / group_size) groups of
+                    # brute-pair work.
+                    gsz = max(cfg.group_size, 1)
+
+                    def make_weight_fn(qi):
+                        def weight_fn(p):
+                            w = sum(
+                                stats_memo[(mi, qi, p)]["surviving_groups"]
+                                for mi in range(n_models)
+                                if (mi, qi, p) in stats_memo
+                            )
+                            w += sum(
+                                -(-_delta_rows(mi, qi, p) // gsz) for mi in range(n_models)
+                            )
+                            return float(w)
+
+                        return weight_fn
+
+                else:
+
+                    def make_weight_fn(qi):
+                        def weight_fn(p):
+                            main = (
+                                sum(
+                                    dev_counts.get((mi, qi, p), 0)
+                                    for mi in range(n_models)
+                                )
+                                if device_assembly
+                                else sum(
+                                    memo[(mi, qi, p)].size
+                                    for mi in range(n_models)
+                                    if (mi, qi, p) in memo
+                                )
+                            )
+                            return float(
+                                main + sum(_delta_rows(mi, qi, p) for mi in range(n_models))
+                            )
+
+                        return weight_fn
+
+                weight_fns = [
+                    make_weight_fn(qi) if cached_plans[qi] is None else None
+                    for qi in range(nq)
+                ]
+            plans = [
+                cached_plans[qi]
+                if cached_plans[qi] is not None
+                else self._plan_cached(q, weight_fn=weight_fns[qi], group_size=plan_group_size)
                 for qi, q in enumerate(queries)
-                if cached_plans[qi] is None
-                for p in candidate_plan_paths(q, cfg.path_length)
             ]
-            stats_memo = {} if use_groups else None
-            if probe_reqs:
-                self._probe_batch(
-                    probe_reqs, queries, q_embs, memo,
-                    use_groups=use_groups, stats_memo=stats_memo, probe_impl=impl,
-                    delta_memo=delta_memo, dev_memo=dev_memo, dev_counts=dev_counts,
+            if plan_span is not None:
+                plan_span.attrs["plan_cache_hits"] = sum(
+                    1 for p in cached_plans if p is not None
                 )
-
-            def _delta_rows(mi, qi, p):
-                rows = delta_memo.get((mi, qi, p))
-                return rows.size if rows is not None else 0
-
-            if use_groups:
-                # grouped cost model: weights are group fan-outs
-                # (surviving groups — the probe's unit of leaf work)
-                # instead of the per-path |DR(o(p_q))| counts the
-                # two-level probe avoids materializing; plan_query's
-                # group_size scale only converts the reported cost to
-                # leaf-row units (selection is scale-invariant).  Delta
-                # buffer rows count as ceil(rows / group_size) groups of
-                # brute-pair work.
-                gsz = max(cfg.group_size, 1)
-
-                def make_weight_fn(qi):
-                    def weight_fn(p):
-                        w = sum(
-                            stats_memo[(mi, qi, p)]["surviving_groups"]
-                            for mi in range(n_models)
-                            if (mi, qi, p) in stats_memo
-                        )
-                        w += sum(
-                            -(-_delta_rows(mi, qi, p) // gsz) for mi in range(n_models)
-                        )
-                        return float(w)
-
-                    return weight_fn
-
-            else:
-
-                def make_weight_fn(qi):
-                    def weight_fn(p):
-                        main = (
-                            sum(
-                                dev_counts.get((mi, qi, p), 0)
-                                for mi in range(n_models)
-                            )
-                            if device_assembly
-                            else sum(
-                                memo[(mi, qi, p)].size
-                                for mi in range(n_models)
-                                if (mi, qi, p) in memo
-                            )
-                        )
-                        return float(
-                            main + sum(_delta_rows(mi, qi, p) for mi in range(n_models))
-                        )
-
-                    return weight_fn
-
-            weight_fns = [
-                make_weight_fn(qi) if cached_plans[qi] is None else None
-                for qi in range(nq)
-            ]
-        plans = [
-            cached_plans[qi]
-            if cached_plans[qi] is not None
-            else self._plan_cached(q, weight_fn=weight_fns[qi], group_size=plan_group_size)
-            for qi, q in enumerate(queries)
-        ]
-        if plan_span is not None:
-            plan_span.attrs["plan_cache_hits"] = sum(
-                1 for p in cached_plans if p is not None
-            )
-        plan_span_cm.__exit__(None, None, None)
-        t_plan = time.perf_counter()
-        _M_STAGE_S.labels(stage="plan").observe(t_plan - t_embed)
         # ---- retrieval: one fused probe per partition for all plans -----
         todo = [
             (qi, p)
@@ -1969,117 +1980,81 @@ class GnnPeEngine:
                 )
             )
         ]
-        # capture grouped traversal stats for the trace funnel (the
-        # surviving-groups rung) — only when someone is actually tracing
-        probe_stats: dict | None = (
-            {} if (trace is not None and use_groups) else None
-        )
-        with obs_trace.span("probe", n_requests=len(todo)):
+        with obs_trace.step("probe", n_requests=len(todo)):
             if todo:
                 self._probe_batch(
                     todo, queries, q_embs, memo, use_groups=use_groups, probe_impl=impl,
-                    stats_memo=probe_stats,
                     delta_memo=delta_memo, dev_memo=dev_memo, dev_counts=dev_counts,
                 )
-            if trace is not None:
-                # one child span per partition — the probe itself is fused
-                # across partitions, so these carry the per-partition row
-                # attribution (main vs delta) rather than separable time
-                main_rows = [0] * n_models
-                delta_rows = [0] * n_models
-                for (mi, _qi, _p), rows in memo.items():
-                    main_rows[mi] += int(rows.size)
-                for (mi, _qi, _p), cnt in dev_counts.items():
-                    main_rows[mi] += int(cnt)
-                for (mi, _qi, _p), rows in delta_memo.items():
-                    delta_rows[mi] += int(rows.size)
-                for mi in range(n_models):
-                    with obs_trace.span(
-                        "partition",
-                        part=mi,
-                        main_rows=main_rows[mi],
-                        delta_rows=delta_rows[mi],
-                    ):
-                        pass
         filter_time = time.perf_counter() - t0
-        _M_STAGE_S.labels(stage="probe").observe(time.perf_counter() - t_plan)
-        g_after = index_mod._GROUP_PAIRS.value
-        l_after = index_mod._LEAF_PAIRS.value
-        _M_FUNNEL.labels(stage="group_pairs").inc(g_after - pairs_before[0])
-        _M_FUNNEL.labels(stage="leaf_pairs").inc(l_after - pairs_before[1])
+        # funnel: the probes' counter deltas (dr cost-model probes included)
+        funnel = {
+            "group_pairs": index_mod._GROUP_PAIRS.value - pairs_before[0],
+            "leaf_pairs": index_mod._LEAF_PAIRS.value - pairs_before[2],
+        }
+        if use_groups:
+            funnel["surviving_groups"] = index_mod._SURVIVING_GROUPS.value - pairs_before[1]
+        for stage, n in funnel.items():
+            _M_FUNNEL.labels(stage=stage).inc(n)
         if trace is not None:
-            trace.add_funnel(
-                group_pairs=g_after - pairs_before[0],
-                leaf_pairs=l_after - pairs_before[1],
-            )
-            surv = 0
-            for sm in (probe_stats, stats_memo if cfg.plan_weight == "dr" else None):
-                if sm:
-                    surv += sum(int(e.get("surviving_groups", 0)) for e in sm.values())
-            if use_groups:
-                trace.add_funnel(surviving_groups=surv)
-                _M_FUNNEL.labels(stage="surviving_groups").inc(surv)
+            trace.add_funnel(**funnel)
         # ---- per-query candidate assembly -------------------------------
-        t_asm = time.perf_counter()
-        asm_span_cm = obs_trace.span("assemble")
-        asm_span = asm_span_cm.__enter__()
-        contributing: list[set] = [set() for _ in range(nq)]
-        per_query_cands: list = []
-        for qi, (q, plan) in enumerate(zip(queries, plans)):
-            st = stats[qi]
-            st.plan = plan
-            candidates = [[] for _ in plan.paths]
-            total_paths = 0
-            for mi, model in enumerate(self.models):
-                dp = delta.parts[mi] if delta is not None else None
-                n_live = model.index.n_paths + (
-                    dp.n_rows - dp.n_tombstones if dp is not None else 0
-                )
-                if n_live <= 0:
-                    continue
-                total_paths += n_live
-                for pi, p in enumerate(plan.paths):
+        with obs_trace.step("assemble") as asm_span:
+            contributing: list[set] = [set() for _ in range(nq)]
+            per_query_cands: list = []
+            for qi, (q, plan) in enumerate(zip(queries, plans)):
+                st = stats[qi]
+                st.plan = plan
+                candidates = [[] for _ in plan.paths]
+                total_paths = 0
+                for mi, model in enumerate(self.models):
+                    dp = delta.parts[mi] if delta is not None else None
+                    n_live = model.index.n_paths + (
+                        dp.n_rows - dp.n_tombstones if dp is not None else 0
+                    )
+                    if n_live <= 0:
+                        continue
+                    total_paths += n_live
+                    for pi, p in enumerate(plan.paths):
+                        if device_assembly:
+                            if dev_counts.get((mi, qi, p), 0):
+                                contributing[qi].add(mi)
+                        else:
+                            rows = memo.get((mi, qi, p))
+                            if rows is not None and rows.size:
+                                candidates[pi].append(model.index.paths[rows])
+                                contributing[qi].add(mi)
+                        if dp is not None:
+                            drows = delta_memo.get((mi, qi, p))
+                            if drows is not None and drows.size:
+                                candidates[pi].append(dp.paths[drows])
+                                contributing[qi].add(mi)
+                cand_arrays = []
+                cand_total = 0
+                for pi, parts in enumerate(candidates):
                     if device_assembly:
-                        if dev_counts.get((mi, qi, p), 0):
-                            contributing[qi].add(mi)
+                        # device rows straight from the probe; delta-buffer
+                        # rows (host, small) ride along as one upload
+                        ent = dev_memo.get((qi, plan.paths[pi]))
+                        arr = self._device_candidates(ent, parts, len(plan.paths[pi]))
+                        n_rows = arr[1]
+                    elif parts:
+                        arr = np.concatenate(parts, axis=0)
+                        n_rows = arr.shape[0]
                     else:
-                        rows = memo.get((mi, qi, p))
-                        if rows is not None and rows.size:
-                            candidates[pi].append(model.index.paths[rows])
-                            contributing[qi].add(mi)
-                    if dp is not None:
-                        drows = delta_memo.get((mi, qi, p))
-                        if drows is not None and drows.size:
-                            candidates[pi].append(dp.paths[drows])
-                            contributing[qi].add(mi)
-            cand_arrays = []
-            cand_total = 0
-            for pi, parts in enumerate(candidates):
-                if device_assembly:
-                    # device rows straight from the probe; delta-buffer
-                    # rows (host, small) ride along as one upload
-                    ent = dev_memo.get((qi, plan.paths[pi]))
-                    arr = self._device_candidates(ent, parts, len(plan.paths[pi]))
-                    n_rows = arr[1]
-                elif parts:
-                    arr = np.concatenate(parts, axis=0)
-                    n_rows = arr.shape[0]
-                else:
-                    arr = np.zeros((0, len(plan.paths[pi])), np.int32)
-                    n_rows = 0
-                cand_arrays.append(arr)
-                cand_total += n_rows
-                st.n_candidates[plan.paths[pi]] = int(n_rows)
-            per_query_cands.append(cand_arrays)
-            st.filter_time = filter_time / nq  # batch stage, amortized
-            st.total_paths = total_paths * max(len(plan.paths), 1)
-            st.candidate_paths = cand_total
-            st.pruning_power = 1.0 - cand_total / max(st.total_paths, 1)
-        batch_cands = sum(st.candidate_paths for st in stats)
-        if asm_span is not None:
-            asm_span.attrs["candidates"] = batch_cands
-        asm_span_cm.__exit__(None, None, None)
-        _M_STAGE_S.labels(stage="assemble").observe(time.perf_counter() - t_asm)
+                        arr = np.zeros((0, len(plan.paths[pi])), np.int32)
+                        n_rows = 0
+                    cand_arrays.append(arr)
+                    cand_total += n_rows
+                    st.n_candidates[plan.paths[pi]] = int(n_rows)
+                per_query_cands.append(cand_arrays)
+                st.filter_time = filter_time / nq  # batch stage, amortized
+                st.total_paths = total_paths * max(len(plan.paths), 1)
+                st.candidate_paths = cand_total
+                st.pruning_power = 1.0 - cand_total / max(st.total_paths, 1)
+            batch_cands = sum(st.candidate_paths for st in stats)
+            if asm_span is not None:
+                asm_span.attrs["candidates"] = batch_cands
         _M_FUNNEL.labels(stage="candidates").inc(batch_cands)
         if trace is not None:
             trace.add_funnel(candidates=batch_cands)
@@ -2087,37 +2062,33 @@ class GnnPeEngine:
         # per-path candidates are duplicate-free (partitions are root-
         # disjoint; delta rows are disjoint from live main rows), so the
         # join may skip its dedup sorts (assume_unique)
-        t_join0 = time.perf_counter()
-        join_span_cm = obs_trace.span("join", impl=join_impl, n_queries=nq)
-        join_span = join_span_cm.__enter__()
-        if join_impl == "device":
-            # one vmapped device program per join step for every group of
-            # same-plan queries — the tick-level batched join
-            t1 = time.perf_counter()
-            results = match_from_candidates_many(
-                self.graph, queries, [plan.paths for plan in plans], per_query_cands,
-                induced=cfg.induced, join_impl="device", assume_unique=True,
-            )
-            join_time = time.perf_counter() - t1
-            for qi, matches in enumerate(results):
-                stats[qi].join_time = join_time / nq  # batch stage, amortized
-                stats[qi].n_matches = len(matches)
-        else:
-            results = []
-            for qi, (q, plan) in enumerate(zip(queries, plans)):
+        with obs_trace.step("join", impl=join_impl, n_queries=nq) as join_span:
+            if join_impl == "device":
+                # one vmapped device program per join step for every group of
+                # same-plan queries — the tick-level batched join
                 t1 = time.perf_counter()
-                matches = match_from_candidates(
-                    self.graph, q, plan.paths, per_query_cands[qi],
-                    induced=cfg.induced, join_impl="numpy", assume_unique=True,
+                results = match_from_candidates_many(
+                    self.graph, queries, [plan.paths for plan in plans], per_query_cands,
+                    induced=cfg.induced, join_impl="device", assume_unique=True,
                 )
-                stats[qi].join_time = time.perf_counter() - t1
-                stats[qi].n_matches = len(matches)
-                results.append(matches)
-        n_matches = sum(len(m) for m in results)
-        if join_span is not None:
-            join_span.attrs["matches"] = n_matches
-        join_span_cm.__exit__(None, None, None)
-        _M_STAGE_S.labels(stage="join").observe(time.perf_counter() - t_join0)
+                join_time = time.perf_counter() - t1
+                for qi, matches in enumerate(results):
+                    stats[qi].join_time = join_time / nq  # batch stage, amortized
+                    stats[qi].n_matches = len(matches)
+            else:
+                results = []
+                for qi, (q, plan) in enumerate(zip(queries, plans)):
+                    t1 = time.perf_counter()
+                    matches = match_from_candidates(
+                        self.graph, q, plan.paths, per_query_cands[qi],
+                        induced=cfg.induced, join_impl="numpy", assume_unique=True,
+                    )
+                    stats[qi].join_time = time.perf_counter() - t1
+                    stats[qi].n_matches = len(matches)
+                    results.append(matches)
+            n_matches = sum(len(m) for m in results)
+            if join_span is not None:
+                join_span.attrs["matches"] = n_matches
         _M_FUNNEL.labels(stage="matches").inc(n_matches)
         if trace is not None:
             trace.add_funnel(matches=n_matches)

@@ -90,6 +90,9 @@ _PAIR_METRIC = REGISTRY.counter(
 )
 _LEAF_PAIRS = _PAIR_METRIC.labels(kind="leaf_pairs")
 _GROUP_PAIRS = _PAIR_METRIC.labels(kind="group_pairs")
+# (query path, group) pairs that pass the group-MBR check: the funnel's
+# surviving_groups rung, counted by every grouped probe (loop and stacked)
+_SURVIVING_GROUPS = _PAIR_METRIC.labels(kind="surviving_groups")
 _PAIR_CHILDREN = {"leaf_pairs": _LEAF_PAIRS, "group_pairs": _GROUP_PAIRS}
 
 
@@ -779,6 +782,7 @@ def _query_index_batch_multi_grouped(items, eps, return_stats, use_pallas):
         rows = _expand_segments(gs[g_surv], counts)
         q_ids = np.repeat(q_surv, counts).astype(np.int64)
         _LEAF_PAIRS.inc(int(rows.size))
+        _SURVIVING_GROUPS.inc(int(g_surv.size))
         p["checked_groups"] = np.bincount(p["q_ids_g"], minlength=Q)
         p["surviving_groups"] = np.bincount(q_surv, minlength=Q)
         p["member_rows"] = np.bincount(q_ids, minlength=Q)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..graphs import Graph
+from ..obs import trace as obs_trace
 from ..kernels.merge_join.ops import (
     dedup_mask,
     expand_pairs,
@@ -683,58 +684,65 @@ def _join_candidates_device_batch(
     yields the same final table set, order only shapes intermediates.
     Returns ``(tables (B, cap, C) device, counts (B,) host, cols)``.
     """
-    bits = _key_bits(n_values)
-    dedup = not assume_unique
-    B = len(cand_groups)
-    b_pad = _mesh_batch(B)
-    if b_pad != B:  # mesh padding: phantom members join nothing
-        empty = [
-            (np.zeros((0, len(pp)), np.int32), 0) for pp in plan_paths
-        ]
-        cand_groups = list(cand_groups) + [empty] * (b_pad - B)
-    cnt = np.asarray([[c[1] for c in grp] for grp in cand_groups], np.int64)  # (B, P)
-    order = np.argsort(cnt.mean(axis=0), kind="stable")
-    first = int(order[0])
-    cap0 = pow2_at_least(int(cnt[:, first].max()), 16)
-    stack0 = _stack_candidates(
-        [grp[first][0] for grp in cand_groups], cnt[:, first], cap0,
-        len(plan_paths[first]),
-    )
+    with obs_trace.step("join", "prepare"):
+        bits = _key_bits(n_values)
+        dedup = not assume_unique
+        B = len(cand_groups)
+        b_pad = _mesh_batch(B)
+        if b_pad != B:  # mesh padding: phantom members join nothing
+            empty = [
+                (np.zeros((0, len(pp)), np.int32), 0) for pp in plan_paths
+            ]
+            cand_groups = list(cand_groups) + [empty] * (b_pad - B)
+        cnt = np.asarray([[c[1] for c in grp] for grp in cand_groups], np.int64)  # (B, P)
+        order = np.argsort(cnt.mean(axis=0), kind="stable")
+        first = int(order[0])
+        cap0 = pow2_at_least(int(cnt[:, first].max()), 16)
+        stack0 = _stack_candidates(
+            [grp[first][0] for grp in cand_groups], cnt[:, first], cap0,
+            len(plan_paths[first]),
+        )
+        count0 = jnp.asarray(cnt[:, first].astype(np.int32))
     tables, valids, counts_dev = _step_fn("init", bits=bits, n_values=n_values, dedup=dedup)(
-        stack0, jnp.asarray(cnt[:, first].astype(np.int32))
+        stack0, count0
     )
-    counts = np.asarray(counts_dev).astype(np.int64)
+    with obs_trace.wait("join"):
+        counts = np.asarray(counts_dev).astype(np.int64)
     cols = list(plan_paths[first])
     remaining = [int(i) for i in order[1:]]
     while remaining and counts.max() > 0:
-        nxt = None
-        for i in remaining:
-            if set(plan_paths[i]) & set(cols):
-                nxt = i
-                break
-        if nxt is None:
-            nxt = remaining[0]
-        remaining.remove(nxt)
-        cand_cols = list(plan_paths[nxt])
-        shared = [c for c in cand_cols if c in cols]
-        new_cols = [c for c in cand_cols if c not in cols]
-        t_idx = tuple(cols.index(c) for c in shared)
-        c_idx = tuple(cand_cols.index(c) for c in shared)
-        n_idx = tuple(cand_cols.index(c) for c in new_cols)
-        capc = pow2_at_least(int(cnt[:, nxt].max()), 16)
-        cstack = _stack_candidates(
-            [grp[nxt][0] for grp in cand_groups], cnt[:, nxt], capc, len(cand_cols)
-        )
-        ccounts = jnp.asarray(cnt[:, nxt].astype(np.int32))
+        with obs_trace.step("join", "prepare"):
+            nxt = None
+            for i in remaining:
+                if set(plan_paths[i]) & set(cols):
+                    nxt = i
+                    break
+            if nxt is None:
+                nxt = remaining[0]
+            remaining.remove(nxt)
+            cand_cols = list(plan_paths[nxt])
+            shared = [c for c in cand_cols if c in cols]
+            new_cols = [c for c in cand_cols if c not in cols]
+            t_idx = tuple(cols.index(c) for c in shared)
+            c_idx = tuple(cand_cols.index(c) for c in shared)
+            n_idx = tuple(cand_cols.index(c) for c in new_cols)
+            capc = pow2_at_least(int(cnt[:, nxt].max()), 16)
+            cstack = _stack_candidates(
+                [grp[nxt][0] for grp in cand_groups], cnt[:, nxt], capc, len(cand_cols)
+            )
+            ccounts = jnp.asarray(cnt[:, nxt].astype(np.int32))
+            if shared:
+                guess_key = (n_values, t_idx, c_idx, n_idx, tables.shape[1:], cstack.shape[1:])
+                cap = pow2_at_least(_CAP_GUESS.get(guess_key, cstack.shape[1]), 16)
+                step_idx = [jnp.asarray(ix, jnp.int32) for ix in (t_idx, c_idx, n_idx)]
         if shared:
-            guess_key = (n_values, t_idx, c_idx, n_idx, tables.shape[1:], cstack.shape[1:])
-            cap = pow2_at_least(_CAP_GUESS.get(guess_key, cstack.shape[1]), 16)
-            step_idx = [jnp.asarray(ix, jnp.int32) for ix in (t_idx, c_idx, n_idx)]
             for _ in range(2):  # second pass only on a cold/overflowed guess
                 tables2, valids2, counts_dev, totals = _step_fn(
                     "joinstep", cap=cap, bits=bits, n_values=n_values, dedup=dedup,
                 )(tables, cstack, ccounts, *step_idx)
-                tmax = int(np.asarray(totals).max())
+                with obs_trace.wait("join"):
+                    totals, counts_h = jax.device_get((totals, counts_dev))
+                tmax = int(totals.max())
                 if tmax <= cap:
                     break
                 cap = pow2_at_least(tmax, 16)
@@ -757,12 +765,15 @@ def _join_candidates_device_batch(
             tables, valids, counts_dev = _step_fn(
                 "cartesian", n_idx=n_idx, bits=bits, n_values=n_values, dedup=dedup
             )(tables, valids, cstack, ccounts)
-        counts = np.asarray(counts_dev).astype(np.int64)
+            with obs_trace.wait("join"):
+                counts_h = np.asarray(counts_dev)
+        counts = counts_h.astype(np.int64)
         cols = cols + new_cols
     # one end-of-join compaction: refine/fetch work scales with the real
     # row counts from here on, not the last pair bucket
     tables, counts_dev = _step_fn("compact", n_values=n_values)(tables, valids)
-    counts = np.asarray(counts_dev).astype(np.int64)
+    with obs_trace.wait("join"):
+        counts = np.asarray(counts_dev).astype(np.int64)
     tables = tables[:, : pow2_at_least(int(max(counts.max(), 1)), 16)]
     return tables, counts[:B], cols
 
@@ -892,49 +903,51 @@ def _refine_device_batch(
     if not counts.max():
         return [np.zeros((0, nq), np.int32) for _ in range(B)]
     assert sorted(cols) == list(range(nq)), f"join must cover all query vertices, got {cols}"
-    if colperms is None:
-        colperms = np.broadcast_to(np.argsort(np.asarray(cols)), (B, nq))
     n_out = B
-    b_pad = max(int(tables.shape[0]), _mesh_batch(B))
-    if b_pad != int(tables.shape[0]):
-        # single-query entries (B=1 public refine / scalar engine path)
-        # arrive unpadded; the shard_map'd refine needs a mesh multiple —
-        # phantom rows are sentinel tables with zero counts
-        tables = jnp.concatenate(
-            [tables, jnp.zeros((b_pad - int(tables.shape[0]),) + tables.shape[1:], tables.dtype)]
+    with obs_trace.step("join", "prepare"):
+        if colperms is None:
+            colperms = np.broadcast_to(np.argsort(np.asarray(cols)), (B, nq))
+        b_pad = max(int(tables.shape[0]), _mesh_batch(B))
+        if b_pad != int(tables.shape[0]):
+            # single-query entries (B=1 public refine / scalar engine path)
+            # arrive unpadded; the shard_map'd refine needs a mesh multiple —
+            # phantom rows are sentinel tables with zero counts
+            tables = jnp.concatenate(
+                [tables, jnp.zeros((b_pad - int(tables.shape[0]),) + tables.shape[1:], tables.dtype)]
+            )
+        if b_pad != B:  # mesh padding (see _mesh_batch): zero-count phantoms
+            qlab = np.concatenate([qlab, np.zeros((b_pad - B, nq), np.int32)])
+            colperms = np.concatenate(
+                [colperms, np.zeros((b_pad - B, nq), colperms.dtype)]
+            )
+            edges = list(edges) + [np.zeros((0, 2), np.int32)] * (b_pad - B)
+            non_edges = list(non_edges) + [np.zeros((0, 2), np.int32)] * (b_pad - B)
+            counts = np.concatenate([counts, np.zeros(b_pad - B, counts.dtype)])
+            B = b_pad
+        inv = jnp.asarray(np.ascontiguousarray(colperms).astype(np.int32))
+        variant, ops, deg_steps, labels = _edge_tensors_device(g)
+        e_cap = pow2_at_least(max(e.shape[0] for e in edges), 4)
+        qe = np.zeros((B, e_cap, 2), np.int32)
+        n_qe = np.zeros(B, np.int32)
+        for b, e in enumerate(edges):
+            qe[b, : e.shape[0]] = e
+            n_qe[b] = e.shape[0]
+        n_max = max(x.shape[0] for x in non_edges)
+        n_cap = pow2_at_least(n_max, 4) if n_max else 0
+        qnon = np.zeros((B, n_cap, 2), np.int32)
+        n_qn = np.zeros(B, np.int32)
+        for b, x in enumerate(non_edges):
+            qnon[b, : x.shape[0]] = x
+            n_qn[b] = x.shape[0]
+        operands = (
+            tables, jnp.asarray(counts.astype(np.int32)),
+            jnp.asarray(qlab), jnp.asarray(qe), jnp.asarray(n_qe),
+            jnp.asarray(qnon), jnp.asarray(n_qn),
+            inv, ops, labels,
         )
-    if b_pad != B:  # mesh padding (see _mesh_batch): zero-count phantoms
-        qlab = np.concatenate([qlab, np.zeros((b_pad - B, nq), np.int32)])
-        colperms = np.concatenate(
-            [colperms, np.zeros((b_pad - B, nq), colperms.dtype)]
-        )
-        edges = list(edges) + [np.zeros((0, 2), np.int32)] * (b_pad - B)
-        non_edges = list(non_edges) + [np.zeros((0, 2), np.int32)] * (b_pad - B)
-        counts = np.concatenate([counts, np.zeros(b_pad - B, counts.dtype)])
-        B = b_pad
-    inv = jnp.asarray(np.ascontiguousarray(colperms).astype(np.int32))
-    variant, ops, deg_steps, labels = _edge_tensors_device(g)
-    e_cap = pow2_at_least(max(e.shape[0] for e in edges), 4)
-    qe = np.zeros((B, e_cap, 2), np.int32)
-    n_qe = np.zeros(B, np.int32)
-    for b, e in enumerate(edges):
-        qe[b, : e.shape[0]] = e
-        n_qe[b] = e.shape[0]
-    n_max = max(x.shape[0] for x in non_edges)
-    n_cap = pow2_at_least(n_max, 4) if n_max else 0
-    qnon = np.zeros((B, n_cap, 2), np.int32)
-    n_qn = np.zeros(B, np.int32)
-    for b, x in enumerate(non_edges):
-        qnon[b, : x.shape[0]] = x
-        n_qn[b] = x.shape[0]
-    rows, ok = _step_fn("refine", variant=variant, deg_steps=deg_steps)(
-        tables, jnp.asarray(counts.astype(np.int32)),
-        jnp.asarray(qlab), jnp.asarray(qe), jnp.asarray(n_qe),
-        jnp.asarray(qnon), jnp.asarray(n_qn),
-        inv, ops, labels,
-    )
-    rows = np.asarray(rows)
-    ok = np.asarray(ok)
+    rows, ok = _step_fn("refine", variant=variant, deg_steps=deg_steps)(*operands)
+    with obs_trace.wait("join"):
+        rows, ok = jax.device_get((rows, ok))
     return [rows[b][ok[b]] for b in range(n_out)]
 
 
@@ -988,42 +1001,43 @@ def match_from_candidates_many(
     from .planner import canonical_form  # function-level: keeps import order
 
     results: list = [None] * len(queries)
-    groups: dict = {}
-    invs: list = []
-    for qi, (q, pp) in enumerate(zip(queries, plan_paths_list)):
-        perm, ckey = canonical_form(q)
-        inv = np.empty(q.n_vertices, np.int64)
-        inv[perm] = np.arange(q.n_vertices)
-        invs.append(inv)
-        canon_pp = tuple(tuple(int(inv[v]) for v in p) for p in pp)
-        groups.setdefault((ckey, canon_pp), []).append(qi)
+    with obs_trace.step("join", "prepare"):
+        groups: dict = {}
+        invs: list = []
+        for qi, (q, pp) in enumerate(zip(queries, plan_paths_list)):
+            perm, ckey = canonical_form(q)
+            inv = np.empty(q.n_vertices, np.int64)
+            inv[perm] = np.arange(q.n_vertices)
+            invs.append(inv)
+            canon_pp = tuple(tuple(int(inv[v]) for v in p) for p in pp)
+            groups.setdefault((ckey, canon_pp), []).append(qi)
+        normed = [_normalize_candidates(c) for c in candidates_list]
     for (ckey, canon_pp), idxs in groups.items():
-        grp = [_normalize_candidates(candidates_list[qi]) for qi in idxs]
         tables, counts, cols = _join_candidates_device_batch(
-            [list(p) for p in canon_pp], grp, g.n_vertices, assume_unique=assume_unique
+            [list(p) for p in canon_pp], [normed[qi] for qi in idxs], g.n_vertices,
+            assume_unique=assume_unique,
         )
         if counts.max():
-            nq = queries[idxs[0]].n_vertices
-            # per-member column map: table columns are canonical ids in
-            # join order; member b's vertex v lives at the column holding
-            # canonical id invs[b][v] — the refine applies it on device,
-            # so rows come back already in each member's own order and
-            # labels/edges are passed in plain member space
-            col_pos = np.argsort(np.asarray(cols))
-            colperms = np.stack([col_pos[invs[qi]] for qi in idxs]).astype(np.int32)
-            labs, es, nons = [], [], []
-            for qi in idxs:
-                lab, e, non = _query_edge_arrays(queries[qi], induced)
-                labs.append(lab)
-                es.append(e)
-                nons.append(non)
+            with obs_trace.step("join", "prepare"):
+                # per-member column map: table columns are canonical ids in
+                # join order; member b's vertex v lives at the column holding
+                # canonical id invs[b][v] — the refine applies it on device,
+                # so rows come back already in each member's own order and
+                # labels/edges are passed in plain member space
+                col_pos = np.argsort(np.asarray(cols))
+                colperms = np.stack([col_pos[invs[qi]] for qi in idxs]).astype(np.int32)
+                labs, es, nons = zip(
+                    *(_query_edge_arrays(queries[qi], induced) for qi in idxs)
+                )
             rows = _refine_device_batch(
-                g, np.stack(labs), es, nons, tables, counts, cols, colperms=colperms
+                g, np.stack(labs), list(es), list(nons), tables, counts, cols,
+                colperms=colperms,
             )
         else:
             rows = [
                 np.zeros((0, queries[idxs[0]].n_vertices), np.int32) for _ in idxs
             ]
-        for k, qi in enumerate(idxs):
-            results[qi] = list(map(tuple, rows[k].tolist()))
+        with obs_trace.step("join", "collect"):
+            for k, qi in enumerate(idxs):
+                results[qi] = list(map(tuple, rows[k].tolist()))
     return results

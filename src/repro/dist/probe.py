@@ -37,6 +37,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from ..core import index as index_mod
 from ..core.index import quantize_query
 from ..core.stacked import StackedIndex, build_stacked, restack_slot, stacked_masks_ref
+from ..obs import trace as obs_trace
 from ..shapes import pow2_at_least
 
 __all__ = ["StackedProbe"]
@@ -108,9 +109,17 @@ class StackedProbe:
             tuple(self._put(x) for x in self.stacked.level_hi0),
         )
         g = self.stacked.groups
-        self._dev_groups = (
-            (self._put(g.hi), self._put(g.lo0), self._put(g.hi0)) if g is not None else None
-        )
+        self._dev_groups = None
+        if g is not None:
+            # groups present in each leaf block: the level-1 accounting
+            # (group pairs checked per surviving (query, block) cell)
+            B = self.stacked.level_hi[-1].shape[1]
+            gib = (g.count.reshape(self.stacked.n_slots, B, g.gpb) > 0).sum(axis=2)
+            self._gib_host = gib.astype(np.int64)
+            self._dev_groups = (
+                self._put(g.hi), self._put(g.lo0), self._put(g.hi0),
+                self._put(gib.astype(np.int32)),
+            )
 
     def update_slot(self, part_i: int, index) -> bool:
         """Elastic re-stacking after partition ``part_i`` compacted: only
@@ -144,6 +153,9 @@ class StackedProbe:
         gpb = self.stacked.groups.gpb if use_groups else 0
 
         def slot_fn(levels, group_bounds, q_cat, q0):
+            """One slot's masks, plus its surviving cells and (grouped)
+            group pairs checked, summed here so that the host reads the
+            probe's funnel counts in the same transfer as the cell count."""
             level_hi, level_lo0, level_hi0 = levels
             alive = None
             for hi, lo0, hi0 in zip(level_hi, level_lo0, level_hi0):
@@ -156,15 +168,16 @@ class StackedProbe:
                     m = m & jnp.repeat(alive, fanout, axis=1)[:, : m.shape[1]]
                 alive = m
             if not use_groups:
-                return (alive,)
-            g_hi, g_lo0, g_hi0 = group_bounds
+                return alive, None, jnp.sum(alive, dtype=jnp.int32), jnp.zeros((), jnp.int32)
+            g_hi, g_lo0, g_hi0, gib = group_bounds
             gkeep = (
                 jnp.repeat(alive, gpb, axis=1)
                 & jnp.all(q_cat[:, None, :] <= g_hi[None] + eps, axis=-1)
                 & jnp.all(q0[:, None, :] <= g_hi0[None] + eps, axis=-1)
                 & jnp.all(q0[:, None, :] >= g_lo0[None] - eps, axis=-1)
             )
-            return (alive, gkeep)
+            checked = jnp.sum(jnp.where(alive, gib[None, :], 0), dtype=jnp.int32)
+            return alive, gkeep, jnp.sum(gkeep, dtype=jnp.int32), checked
 
         mapped = jax.vmap(slot_fn)
         if self.mesh is not None:
@@ -175,29 +188,33 @@ class StackedProbe:
         self._mask_fns[key] = fn
         return fn
 
-    def _device_masks_dev(self, q_cat, q0, eps, use_groups):
-        """(S, Q, Dcat/D0) query tensors → (alive, gkeep) DEVICE masks."""
+    def _upload_queries(self, q_cat, q0):
+        """(S, Q, Dcat/D0) host query tensors → device tensors with Q
+        bucketed to a power of two (padded queries carry +inf and never
+        survive)."""
         S, Q = q_cat.shape[:2]
         Qp = pow2_at_least(Q)
-        if Qp != Q:  # bucket Q: padded queries carry +inf and never survive
+        if Qp != Q:
             q_cat = np.concatenate(
                 [q_cat, np.full((S, Qp - Q, q_cat.shape[2]), np.inf, np.float32)], axis=1
             )
             q0 = np.concatenate([q0, np.zeros((S, Qp - Q, q0.shape[2]), np.float32)], axis=1)
+        return self._put(q_cat), self._put(q0)
+
+    def _masks_dev(self, q_dev, q0_dev, eps, use_groups):
+        """Uploaded query tensors → DEVICE ``(alive, gkeep, cells,
+        checked)`` at the bucketed query count: the masks, and per slot
+        the surviving cells and the group pairs checked."""
         group_bounds = self._dev_groups if use_groups else None
-        out = self._mask_fn(use_groups, eps)(
-            self._dev_levels, group_bounds, self._put(q_cat), self._put(q0)
-        )
-        alive = out[0][:, :Q]
-        gkeep = out[1][:, :Q] if use_groups else None
-        return alive, gkeep
+        return self._mask_fn(use_groups, eps)(self._dev_levels, group_bounds, q_dev, q0_dev)
 
     def _device_masks(self, q_cat, q0, eps, use_groups, device_stage):
         """(S, Q, Dcat/D0) query tensors → (alive, gkeep) numpy masks."""
         if device_stage == "numpy":
             return stacked_masks_ref(self.stacked, q_cat, q0, eps, use_groups)
-        alive, gkeep = self._device_masks_dev(q_cat, q0, eps, use_groups)
-        return np.asarray(alive), (np.asarray(gkeep) if use_groups else None)
+        Q = q_cat.shape[1]
+        alive, gkeep, _, _ = self._masks_dev(*self._upload_queries(q_cat, q0), eps, use_groups)
+        return np.asarray(alive[:, :Q]), (np.asarray(gkeep[:, :Q]) if use_groups else None)
 
     # ------------------------------------------------------------------
     # full probe: device masks → cross-partition leaf stage
@@ -281,6 +298,7 @@ class StackedProbe:
             checked = np.einsum("sqb,sb->sq", alive, groups_in_block)
             index_mod._GROUP_PAIRS.inc(int(checked.sum()))
             pi, qi, gi = np.nonzero(gkeep)
+            index_mod._SURVIVING_GROUPS.inc(int(pi.size))
             starts = g.start[pi, gi]
             counts = g.count[pi, gi]
         else:
@@ -417,20 +435,12 @@ class StackedProbe:
             if g is not None:
                 d["g_start"] = jnp.asarray(g.start.astype(np.int32))
                 d["g_count"] = jnp.asarray(g.count.astype(np.int32))
-                # groups present in each leaf block (level-1 accounting):
-                # static per stacked identity, so built once here — and
-                # its host twin serves the stats path without a refetch
-                B = st.level_hi[-1].shape[1]
-                gib = (g.count.reshape(st.n_slots, B, g.gpb) > 0).sum(axis=2)
-                # host twin lives OUTSIDE the dict: the dict is a jit
-                # operand, and a NumPy leaf would re-upload every call
-                self._gib_host = gib.astype(np.int64)
-                d["gib"] = jnp.asarray(gib.astype(np.int32))
             self._dev_leaf = d
         return self._dev_leaf
 
     def _cells_fn(self, use_groups: bool, cell_cap: int):
-        """Jitted survivor-cell expansion: mask → (pi, qi, starts, counts)."""
+        """Jitted survivor-cell expansion: mask → (pi, qi, starts, counts,
+        total pairs, pairs per slot)."""
         key = ("cells", use_groups, cell_cap)
         fn = self._leaf_fns.get(key)
         if fn is None:
@@ -446,12 +456,14 @@ class StackedProbe:
                     starts = ci.astype(jnp.int32) * bs
                     counts = jnp.clip(n_paths[pi] - starts, 0, bs)
                 counts = jnp.where(cvalid, counts, 0).astype(jnp.int32)
+                pi = pi.astype(jnp.int32)
                 return (
-                    pi.astype(jnp.int32),
+                    pi,
                     qi.astype(jnp.int32),
                     starts.astype(jnp.int32),
                     counts,
                     jnp.sum(counts),
+                    jnp.zeros((mask.shape[0],), jnp.int32).at[pi].add(counts),
                 )
 
             fn = jax.jit(cells)
@@ -575,62 +587,22 @@ class StackedProbe:
                 else {"scanned_blocks": 0, "scanned_paths": 0}
             )
             return per_b, pc, [[dict(zero) for _ in range(Q)] for _ in range(n_parts)]
-        parts = [q_emb] + (
-            [np.asarray(q_multi[i], np.float32) for i in range(st.n_gnn)] if st.n_gnn else []
-        )
-        cat = np.concatenate(parts, axis=2) if len(parts) > 1 else q_emb
         S = st.n_slots
-        q_cat = np.zeros((S, Q, cat.shape[2]), np.float32)
-        q0 = np.zeros((S, Q, q_emb0.shape[2]), np.float32)
-        q_cat[st.slot_of] = cat
-        q0[st.slot_of] = q_emb0
-
-        alive, gkeep = self._device_masks_dev(q_cat, q0, eps, use_groups)
-        mask = gkeep if use_groups else alive
-        n_cells = int(jnp.sum(mask))
-        leaf = self._leaf_tensors()
-        dummy = jnp.zeros((1, 1), jnp.int32)
-        g_start = leaf.get("g_start", dummy)
-        g_count = leaf.get("g_count", dummy)
-        if n_cells:
-            cell_cap = pow2_at_least(n_cells, 16)
-            pi, qi, starts, counts, total_dev = self._cells_fn(use_groups, cell_cap)(
-                mask, n_cells, leaf["n_paths"], g_start, g_count
+        quantized = st.emb_q is not None
+        hashed = quantized and st.label_hash is not None and q_label_hash is not None
+        with obs_trace.step("probe", "prepare"):
+            parts = [q_emb] + (
+                [np.asarray(q_multi[i], np.float32) for i in range(st.n_gnn)]
+                if st.n_gnn else []
             )
-            total = int(total_dev)
-        else:
-            total = 0
-        if total > self.leaf_pair_cap:
-            # pathological fan-out: chunked host expansion (bounded host
-            # memory), then one upload of the gathered vertex rows —
-            # probe() maintains the pair counters itself
-            return self._probe_device_fallback(
-                q_emb, q_emb0, q_multi, q_label_hash, eps, use_groups,
-                use_pallas, return_stats, live_mask,
-            )
-        index_mod._LEAF_PAIRS.inc(total)
-        if total:
-            # cells only (not pairs) cross back to the host here — the
-            # same per-partition cost signal as the host path
-            slot_lp = np.bincount(
-                np.asarray(pi), weights=np.asarray(counts), minlength=S
-            ).astype(np.int64)
-            self.part_leaf_pairs += slot_lp[st.slot_of]
-        if use_groups:
-            # level-1 accounting matches the host probe: groups checked
-            # per surviving (query, block) cell (gib cached in _leaf_tensors)
-            checked_dev = jnp.einsum("sqb,sb->sq", alive.astype(jnp.int32), leaf["gib"])
-            index_mod._GROUP_PAIRS.inc(int(jnp.sum(checked_dev)))
-        if total == 0:
-            per_b = [empty_b for _ in range(Q)]
-            combo_counts = np.zeros(S * Q, np.int64)
-        else:
-            pair_cap = pow2_at_least(total, 16)
-            quantized = leaf["emb_q"] is not None
-            hashed = quantized and "lh_hi" in leaf and q_label_hash is not None
-            qq = (
-                jnp.asarray(quantize_query(q_cat)) if quantized else jnp.zeros((1,), jnp.int8)
-            )
+            cat = np.concatenate(parts, axis=2) if len(parts) > 1 else q_emb
+            q_cat = np.zeros((S, Q, cat.shape[2]), np.float32)
+            q0 = np.zeros((S, Q, q_emb0.shape[2]), np.float32)
+            q_cat[st.slot_of] = cat
+            q0[st.slot_of] = q_emb0
+            masks_in = self._upload_queries(q_cat, q0)  # Q bucketed
+            pairs_in = (jnp.asarray(q_cat), jnp.asarray(q0))  # Q as is
+            qq = jnp.asarray(quantize_query(q_cat)) if quantized else jnp.zeros((1,), jnp.int8)
             if hashed:
                 qh = np.asarray(q_label_hash)
                 qh_hi = jnp.asarray((qh >> 32).astype(np.int32))
@@ -639,29 +611,71 @@ class StackedProbe:
                 qh_hi = qh_lo = jnp.zeros((1,), jnp.int32)
             has_live = live_mask is not None
             live = jnp.asarray(live_mask) if has_live else jnp.zeros((1, 1), bool)
-            verts_s, counts_b, combo_counts = self._pairs_fn(
-                pair_cap, quantized, hashed, has_live, eps
-            )(
-                pi, qi, starts, counts, total_dev,
-                jnp.asarray(q_cat), jnp.asarray(q0), qq, qh_hi, qh_lo, leaf, live,
+            leaf = self._leaf_tensors()
+
+        alive_p, gkeep_p, cells_s, checked_s = self._masks_dev(*masks_in, eps, use_groups)
+        mask = (gkeep_p if use_groups else alive_p)[:, :Q]
+        with obs_trace.wait("probe"):
+            cells_s, checked_s = jax.device_get((cells_s, checked_s))
+        n_cells = int(cells_s.sum())
+        dummy = jnp.zeros((1, 1), jnp.int32)
+        g_start = leaf.get("g_start", dummy)
+        g_count = leaf.get("g_count", dummy)
+        total = 0
+        if n_cells:
+            cell_cap = pow2_at_least(n_cells, 16)
+            pi, qi, starts, counts, total_dev, slot_lp = self._cells_fn(use_groups, cell_cap)(
+                mask, n_cells, leaf["n_paths"], g_start, g_count
             )
-            counts_b = np.asarray(counts_b)
-            combo_counts = np.asarray(combo_counts)
-            offs = np.concatenate([[0], np.cumsum(counts_b)])
-            # each query's rows are a power-of-two-long slice (the join
-            # reads only ``count`` of them), so the eager slices compile a
-            # handful of shapes, not one per candidate count
-            lens = [pow2_at_least(int(c), 16) for c in counts_b]
-            verts_p = jnp.pad(verts_s, ((0, max(lens)), (0, 0)))
-            per_b = [
-                (verts_p[int(offs[b]) : int(offs[b]) + lens[b]], int(counts_b[b]))
-                for b in range(Q)
-            ]
-        cc = combo_counts.reshape(S, Q)
-        part_counts = cc[st.slot_of.astype(np.int64)]
+            with obs_trace.wait("probe"):
+                total, slot_lp = jax.device_get((total_dev, slot_lp))
+            total = int(total)
+        if total > self.leaf_pair_cap:
+            # pathological fan-out: chunked host expansion (bounded host
+            # memory), then one upload of the gathered vertex rows —
+            # probe() maintains the pair counters itself
+            return self._probe_device_fallback(
+                q_emb, q_emb0, q_multi, q_label_hash, eps, use_groups,
+                use_pallas, return_stats, live_mask,
+            )
+        if total == 0:
+            per_b = [empty_b for _ in range(Q)]
+            combo_counts = np.zeros(S * Q, np.int64)
+        else:
+            verts_s, counts_b, combo_counts = self._pairs_fn(
+                pow2_at_least(total, 16), quantized, hashed, has_live, eps
+            )(
+                pi, qi, starts, counts, total_dev, *pairs_in, qq, qh_hi, qh_lo, leaf, live,
+            )
+            with obs_trace.wait("probe"):
+                counts_b, combo_counts = jax.device_get((counts_b, combo_counts))
+            with obs_trace.step("probe", "slice"):
+                offs = np.concatenate([[0], np.cumsum(counts_b)])
+                # each query's rows are a power-of-two-long slice (the join
+                # reads only ``count`` of them), so the eager slices compile
+                # a handful of shapes, not one per candidate count
+                lens = [pow2_at_least(int(c), 16) for c in counts_b]
+                verts_p = jnp.pad(verts_s, ((0, max(lens)), (0, 0)))
+                per_b = [
+                    (verts_p[int(offs[b]) : int(offs[b]) + lens[b]], int(counts_b[b]))
+                    for b in range(Q)
+                ]
+        with obs_trace.step("probe", "account"):
+            index_mod._LEAF_PAIRS.inc(total)
+            if use_groups:
+                # level-1 accounting matches the host probe: groups checked
+                # per surviving (query, block) cell, and groups surviving
+                index_mod._GROUP_PAIRS.inc(int(checked_s.sum()))
+                index_mod._SURVIVING_GROUPS.inc(n_cells)
+            if total:
+                # the same per-partition cost signal as the host path
+                self.part_leaf_pairs += slot_lp.astype(np.int64)[st.slot_of]
+            part_counts = np.asarray(combo_counts).reshape(S, Q)[st.slot_of.astype(np.int64)]
         if not return_stats:
             return per_b, part_counts
-        stats = self._device_probe_stats(alive, gkeep, use_groups, Q)
+        stats = self._device_probe_stats(
+            alive_p[:, :Q], gkeep_p[:, :Q] if use_groups else None, use_groups, Q
+        )
         return per_b, part_counts, stats
 
     def _device_probe_stats(self, alive, gkeep, use_groups, Q):
@@ -672,9 +686,7 @@ class StackedProbe:
         stats = []
         if use_groups:
             g = st.groups
-            self._leaf_tensors()  # ensure the cached host twin exists
-            gib = self._gib_host
-            checked = np.einsum("sqb,sb->sq", alive_np, gib)
+            checked = np.einsum("sqb,sb->sq", alive_np, self._gib_host)
             gkeep_np = np.asarray(gkeep)
             surviving = gkeep_np.sum(axis=2)
             # member rows per (slot, probe): surviving groups' counts

@@ -5,20 +5,27 @@ A trace is a tree of :class:`Span`s covering the serving pipeline::
     request
     ├─ admission
     ├─ queue_wait
-    └─ execute
-       └─ match_many (per engine call)
-          ├─ embed
-          ├─ plan            (attrs: cache_hits / cache_misses)
-          ├─ probe           (children: one span per partition probed,
-          │                   attrs: main rows vs delta rows)
-          ├─ assemble
-          ├─ join            (attrs: per-step pair counts live on the
-          │                   engine side; retries on the service side)
-          └─ cache_store
+    ├─ execute    (a tick's riders other than its lead, whose trace the
+    │              engine's spans land in)
+    ├─ cache_lookup / cache_store   (result cache on)
+    ├─ embed      ─ embed.stars, embed.encode, embed.wait
+    ├─ plan       (attrs: plan_cache_hits)
+    ├─ probe      ─ probe.prepare, probe.wait, probe.slice, probe.account
+    ├─ assemble   (attrs: candidates)
+    └─ join       ─ join.prepare, join.wait, join.collect
 
 plus a ``funnel`` dict on the trace itself carrying the paper's pruning
 ladder: group MBR pairs in → surviving groups → leaf pairs → candidates
 → matches.
+
+Stages and their steps open through :func:`step`, which times the block
+into a registry histogram (``gnnpe_engine_stage_seconds{stage}`` or
+``gnnpe_engine_step_seconds{stage,step}``), opens a
+``jax.profiler.TraceAnnotation`` named ``gnnpe.<stage>[.<step>]`` (so a
+profile shows it on the device trace's clock, on the calling thread), and
+adds a child span when a trace is current.  Steps exist on the stacked
+probe + device join path; :func:`wait` marks a step that blocks on the
+device and counts its reads in ``gnnpe_engine_device_syncs_total``.
 
 Tracing is sampled (``trace_rate``) with a deterministic counter-based
 sampler — no RNG, so tests are exactly reproducible — and finished
@@ -35,6 +42,8 @@ import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from . import metrics as _metrics
 
 __all__ = [
@@ -44,6 +53,8 @@ __all__ = [
     "TRACER",
     "current_trace",
     "span",
+    "step",
+    "wait",
     "trace_query",
 ]
 
@@ -286,3 +297,119 @@ def span(name: str, **attrs: object):
 
 def trace_query(qid: object):
     return TRACER.trace_query(qid)
+
+
+# ---------------------------------------------------------------------------
+# stages and steps: histogram + profiler annotation + (traced) child span
+# ---------------------------------------------------------------------------
+
+_M_STAGE_S = _metrics.REGISTRY.histogram(
+    "gnnpe_engine_stage_seconds",
+    "Wall seconds per fused pipeline stage",
+    labels=("stage",),
+)
+_M_STEP_S = _metrics.REGISTRY.histogram(
+    "gnnpe_engine_step_seconds",
+    "Wall time of the steps inside an engine stage (host work, or a wait on the device)",
+    labels=("stage", "step"),
+)
+_M_SYNCS = _metrics.REGISTRY.counter(
+    "gnnpe_engine_device_syncs_total",
+    "Host waits for device results on the served path, by stage",
+    labels=("stage",),
+)
+# label children and annotation names by key, so an open costs no
+# label-dict lookup or string formatting
+_SCOPES: Dict[tuple, tuple] = {}
+_SYNC_CHILDREN: Dict[str, object] = {}
+_PROFILING = TraceAnnotation.is_enabled  # a profiler session is collecting
+_perf = time.perf_counter
+
+
+def _scope_of(stage: str, step_name: Optional[str]) -> tuple:
+    key = (stage, step_name)
+    sc = _SCOPES.get(key)
+    if sc is None:
+        if step_name is None:
+            sc = (_M_STAGE_S.labels(stage=stage), f"gnnpe.{stage}", stage)
+        else:
+            name = f"{stage}.{step_name}"
+            sc = (_M_STEP_S.labels(stage=stage, step=step_name), f"gnnpe.{name}", name)
+        _SCOPES[key] = sc
+    return sc
+
+
+class _Step:
+    """One timed block (see :func:`step`).  A stage's span is pushed on
+    entry, so that its steps nest under it; a step is a leaf, appended to
+    the open span on exit."""
+
+    __slots__ = ("scope", "attrs", "is_stage", "syncs", "tr", "span", "ann", "t0")
+
+    def __init__(self, stage: str, step_name: Optional[str], attrs: dict, syncs=None) -> None:
+        self.scope = _scope_of(stage, step_name)
+        self.attrs = attrs
+        self.is_stage = step_name is None
+        self.syncs = syncs  # the sync counter's child, for a wait
+
+    def __enter__(self) -> Optional[Span]:
+        tr = self.tr = getattr(TRACER._local, "trace", None)
+        self.span = None
+        if tr is not None and self.is_stage:
+            self.span = s = tr.push(self.scope[2])
+            if self.attrs:
+                s.attrs.update(self.attrs)
+        if _PROFILING():
+            label = self.scope[1]
+            self.ann = (
+                TraceAnnotation(label, rid=tr.qid) if tr is not None and self.is_stage
+                else TraceAnnotation(label)
+            )
+            self.ann.__enter__()
+        else:
+            self.ann = None
+        self.t0 = _perf()
+        return self.span
+
+    def __exit__(self, *exc) -> bool:
+        t1 = _perf()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        self.scope[0].observe(t1 - self.t0)
+        if self.syncs is not None:
+            self.syncs.inc()
+        tr = self.tr
+        if tr is not None:
+            s = self.span
+            if s is None:  # a step: a leaf under the open span
+                s = Span(self.scope[2])
+                if self.attrs:
+                    s.attrs.update(self.attrs)
+                tr.current.children.append(s)
+            s.t0, s.t1 = self.t0, t1
+            if s is self.span:
+                tr.pop(s)
+        return False
+
+
+def step(stage: str, step: Optional[str] = None, **attrs: object) -> _Step:
+    """Time a block as stage ``stage`` (``step=None``) or as one of its
+    steps.  Always observes ``gnnpe_engine_stage_seconds{stage}`` or
+    ``gnnpe_engine_step_seconds{stage,step}`` (no-ops under
+    ``obs.disable()``) and opens the profiler annotation
+    ``gnnpe.<stage>`` (with ``rid=<trace id>`` when traced) or
+    ``gnnpe.<stage>.<step>``; with a trace current it also adds a child
+    span named ``<stage>`` or ``<stage>.<step>`` under the open span,
+    timed by the same two clock reads.  Yields that span or ``None``."""
+    return _Step(stage, step, attrs)
+
+
+def wait(stage: str) -> _Step:
+    """The ``wait`` step of ``stage``: one host wait for device results
+    (a ``jax.device_get`` of several arrays is one wait), counted in
+    ``gnnpe_engine_device_syncs_total{stage}``."""
+    child = _SYNC_CHILDREN.get(stage)
+    if child is None:
+        child = _SYNC_CHILDREN[stage] = _M_SYNCS.labels(stage=stage)
+    return _Step(stage, "wait", {}, child)
+

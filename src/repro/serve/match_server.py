@@ -48,6 +48,8 @@ import dataclasses
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from ..obs.export import EVENTS
 from ..obs.metrics import REGISTRY as _OBS
 from .errors import QueueFull
@@ -399,12 +401,13 @@ class MatchServer:
             join_impl=self.cfg.join_impl,
         )
         t_tick = time.perf_counter()
-        if isolate:
-            results = self.engine.match_many_isolated(queries, **kw)
-            n_errors = sum(1 for ok, _ in results if not ok)
-        else:
-            results = self.engine.match_many(queries, **kw)
-            n_errors = 0
+        with TraceAnnotation("gnnpe.tick"):
+            if isolate:
+                results = self.engine.match_many_isolated(queries, **kw)
+                n_errors = sum(1 for ok, _ in results if not ok)
+            else:
+                results = self.engine.match_many(queries, **kw)
+                n_errors = 0
         wall = time.perf_counter() - t_tick
         self.tick_stats.append(
             {

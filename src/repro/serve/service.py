@@ -64,6 +64,8 @@ import dataclasses
 import time
 from concurrent.futures import ThreadPoolExecutor
 
+from jax.profiler import TraceAnnotation
+
 from ..obs.export import EVENTS, MetricsHTTPServer
 from ..obs.metrics import REGISTRY as _OBS
 from ..obs.trace import TRACER
@@ -99,6 +101,24 @@ _M_SHED = _OBS.counter(
     "gnnpe_service_shed_total",
     "Shed/evicted submissions by reason",
     labels=("reason",),
+)
+# where a request's time goes outside the engine: waiting in the queue,
+# crossing between the event loop and the engine thread, and the loop
+# idle with nothing queued (the engine thread then idles too)
+_M_QUEUE_WAIT_S = _OBS.histogram(
+    "gnnpe_service_queue_wait_seconds",
+    "Per request: from (re)queueing to the start of its tick",
+)
+_M_HANDOFF_S = _OBS.histogram(
+    "gnnpe_service_handoff_seconds",
+    "Per query tick: event loop to engine thread (to_engine) and back (to_loop)",
+    labels=("leg",),
+)
+_M_HANDOFF_TO_ENGINE = _M_HANDOFF_S.labels(leg="to_engine")
+_M_HANDOFF_TO_LOOP = _M_HANDOFF_S.labels(leg="to_loop")
+_M_IDLE_S = _OBS.histogram(
+    "gnnpe_service_idle_seconds",
+    "Serve-loop waits on its wake event with no work queued",
 )
 
 
@@ -632,10 +652,13 @@ class MatchService:
             if not self._has_work():
                 self._wake.clear()
                 if not self._has_work():  # submit may have raced the clear
-                    try:
-                        await asyncio.wait_for(self._wake.wait(), self.cfg.idle_tick_s)
-                    except (asyncio.TimeoutError, TimeoutError):
-                        pass  # heartbeat: retry deferred compaction installs
+                    t_idle = time.perf_counter()
+                    with TraceAnnotation("gnnpe.service.idle"):
+                        try:
+                            await asyncio.wait_for(self._wake.wait(), self.cfg.idle_tick_s)
+                        except (asyncio.TimeoutError, TimeoutError):
+                            pass  # heartbeat: retry deferred compaction installs
+                    _M_IDLE_S.observe(time.perf_counter() - t_idle)
                 if not self._running:
                     break
             if self.server.update_queue:
@@ -663,6 +686,7 @@ class MatchService:
         # pruning funnel); every traced rider gets its queue_wait span
         lead = None
         for req in batch:
+            _M_QUEUE_WAIT_S.observe(t_exec0 - req.t_queued)
             if req.trace is not None:
                 req.trace.add_span(
                     "queue_wait", req.t_queued, t_exec0, attempt=req.attempts
@@ -671,12 +695,18 @@ class MatchService:
                     lead = req.trace
 
         def _exec():
+            _M_HANDOFF_TO_ENGINE.observe(time.perf_counter() - t_handoff)
             with TRACER.adopt(lead):
-                return self.server.execute_batch(queries, isolate=True)
+                out = self.server.execute_batch(queries, isolate=True)
+            return out, time.perf_counter()
 
+        t_handoff = time.perf_counter()
         fut = loop.run_in_executor(self._engine_pool, _exec)
         try:
-            results, _ = await asyncio.wait_for(fut, timeout=self.cfg.attempt_timeout_s)
+            (results, _), t_returned = await asyncio.wait_for(
+                fut, timeout=self.cfg.attempt_timeout_s
+            )
+            _M_HANDOFF_TO_LOOP.observe(time.perf_counter() - t_returned)
         except (asyncio.TimeoutError, TimeoutError):
             # the tick is stuck (slow or hung engine call).  The engine
             # thread will finish it eventually — single-thread executor

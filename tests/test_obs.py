@@ -5,15 +5,19 @@ Contracts under test:
 
 - registry: labeled children, histogram bucketing, idempotent
   registration, and EXACT sums under concurrent increments (8 threads);
-- tracing: a traced ``match_many`` yields the full stage tree — probe
-  partition children match the partitions probed, the funnel equals the
-  ``PAIR_COUNTERS`` deltas, and per-stage latencies sum (within slack)
-  to the end-to-end wall;
+- tracing: a traced ``match_many`` yields the full stage tree with each
+  stage's steps under it (their time inside the stage's), the funnel
+  equals the ``PAIR_COUNTERS`` deltas (``surviving_groups`` with or
+  without a trace), per-partition rows attribute through
+  ``partition_stats()``, and per-stage latencies sum (within slack) to
+  the end-to-end wall;
 - export: Prometheus text round-trips through the bundled parser, the
   JSON snapshot equals the registry state, and the /metrics endpoint
   serves both;
 - service accounting: across a faulted ``MatchService`` run the
-  per-status counters sum exactly to submitted — no lost requests.
+  per-status counters sum exactly to submitted — no lost requests; a
+  plain run records each request's queue wait, both hand-off legs of
+  each tick, and the loop's idle time.
 
 Registry metrics are process-global and cumulative, so every assertion
 on engine/service metrics works in deltas, never absolutes.
@@ -42,6 +46,7 @@ from repro.obs import (
     trace_query,
     write_json_snapshot,
 )
+from repro.dist.probe import StackedProbe
 from repro.serve.faults import FaultSpec, FlakyEngine
 from repro.serve.service import MatchService, ServiceConfig
 
@@ -59,6 +64,26 @@ def _engine(g=None, **overrides):
         group_size=4, seed=7, **overrides,
     )
     return GnnPeEngine(cfg).build(g)
+
+
+def _hist(name, field="sum", **labels):
+    """A registry histogram child's sum or count (0 before its first
+    observation)."""
+    m = REGISTRY.get(name)
+    for v in (m.snapshot()["values"] if m is not None else []):
+        if all(v["labels"].get(k) == x for k, x in labels.items()):
+            return v[field]
+    return 0
+
+
+def _counter_total(name, **labels):
+    """Sum of a registry counter over the children matching ``labels``."""
+    m = REGISTRY.get(name)
+    return sum(
+        v["value"]
+        for v in (m.snapshot()["values"] if m is not None else [])
+        if all(v["labels"].get(k) == x for k, x in labels.items())
+    )
 
 
 def _queries(g, n=4, size=4, seed0=50):
@@ -169,6 +194,7 @@ def test_traced_match_many_funnel_and_stages():
     eng.match_many(qs)  # warm compile outside the trace
     TRACER.trace_rate = 1.0
     before = dict(index_mod.PAIR_COUNTERS)
+    rows_before = [p["probe_rows"] for p in eng.partition_stats()]
     with trace_query("probe-test") as tr:
         assert tr is not None
         eng.match_many(qs)
@@ -186,15 +212,16 @@ def test_traced_match_many_funnel_and_stages():
     # stage tree: embed/plan/probe/assemble/join all present, once each
     for name in ("embed", "plan", "probe", "assemble", "join"):
         assert len(tr.root.find(name)) == 1, name
-    # per-partition children under the probe span, one per partition
-    # probed, each attributing main vs delta rows
-    parts = tr.root.find("partition")
-    assert parts, "probe span has no partition children"
-    ids = [s.attrs["part"] for s in parts]
-    assert len(ids) == len(set(ids)) <= eng.cfg.n_partitions
-    assert sum(s.attrs["main_rows"] + s.attrs["delta_rows"] for s in parts) > 0
-    for s in parts:
-        assert s.attrs["delta_rows"] == 0  # no deltas applied yet
+    # per-partition attribution through partition_stats(): the rows
+    # each partition served this batch sum to the batch's candidates
+    parts = eng.partition_stats()
+    assert len(parts) <= eng.cfg.n_partitions
+    rows = [p["probe_rows"] - b for p, b in zip(parts, rows_before)]
+    assert all(r >= 0 for r in rows)
+    assert sum(rows) == tr.funnel["candidates"] > 0
+    for p in parts:
+        assert p["delta_rows"] == 0  # no deltas applied yet
+    assert not tr.root.find("partition")  # no per-partition spans
 
     # stage latencies sum (within slack) to the traced wall time
     stage_s = sum(
@@ -212,6 +239,71 @@ def test_traced_match_many_funnel_and_stages():
     d = tr.as_dict()
     assert d["funnel"] == tr.funnel
     json.dumps(d)  # round-trippable
+
+
+STEPS = {
+    "embed": ("stars", "encode", "wait"),
+    "probe": ("prepare", "wait", "slice", "account"),
+    "join": ("prepare", "wait", "collect"),
+}
+
+
+@pytest.mark.parametrize("probe_impl,join_impl", [("stacked", "device"), ("loop", "numpy")])
+def test_traced_match_many_records_every_step(monkeypatch, probe_impl, join_impl):
+    """Each stage's steps sit under its span and inside its time, in the
+    trace and in the step histogram; the served path (stacked probe,
+    device join) has every step, the loop probe with the NumPy join only
+    the embedding's.  Device waits are counted, and a trace no longer
+    makes the stacked probe build per-partition stats."""
+    eng = _engine(index_kind="grouped", probe_impl=probe_impl, join_impl=join_impl)
+    qs = _queries(eng.graph, n=3)
+    eng.match_many(qs)  # warm compile outside the trace
+
+    def no_stats(*a, **kw):
+        raise AssertionError("per-partition stats built without a dr cost model")
+
+    monkeypatch.setattr(StackedProbe, "_device_probe_stats", no_stats)
+    served = probe_impl == "stacked"
+    syncs0 = _counter_total("gnnpe_engine_device_syncs_total")
+    stage0 = {s: _hist("gnnpe_engine_stage_seconds", stage=s) for s in STEPS}
+    step0 = {
+        (s, x): _hist("gnnpe_engine_step_seconds", stage=s, step=x)
+        for s, xs in STEPS.items() for x in xs
+    }
+    TRACER.trace_rate = 1.0
+    with trace_query("steps") as tr:
+        eng.match_many(qs)
+    for stage, steps in STEPS.items():
+        (span,) = tr.root.find(stage)
+        want = steps if served or stage == "embed" else ()
+        assert {c.name for c in span.children} == {f"{stage}.{x}" for x in want}, stage
+        assert sum(c.duration_s for c in span.children) <= span.duration_s
+        stage_s = _hist("gnnpe_engine_stage_seconds", stage=stage) - stage0[stage]
+        step_s = [
+            _hist("gnnpe_engine_step_seconds", stage=stage, step=x) - step0[(stage, x)]
+            for x in steps
+        ]
+        assert all(v > 0 for v in step_s[: len(want)]) and sum(step_s) <= stage_s
+    syncs = _counter_total("gnnpe_engine_device_syncs_total") - syncs0
+    assert syncs >= (1 + 3 + 3 if served else 1)  # embed; probe; join init, compact, refine
+
+
+def test_surviving_groups_counted_without_a_trace():
+    """The surviving-groups rung is always on: untraced, the stacked
+    probe's device sums equal the loop probe's count."""
+    eng = _engine(index_kind="grouped")
+    qs = _queries(eng.graph, n=3)
+    assert TRACER.current() is None
+    counts = {}
+    for impl, jimpl in (("loop", "numpy"), ("stacked", "device"), ("stacked", "numpy")):
+        eng.match_many(qs, probe_impl=impl, join_impl=jimpl)  # warm
+        before = _counter_total("gnnpe_funnel_total", stage="surviving_groups")
+        eng.match_many(qs, probe_impl=impl, join_impl=jimpl)
+        counts[(impl, jimpl)] = (
+            _counter_total("gnnpe_funnel_total", stage="surviving_groups") - before
+        )
+    assert counts[("loop", "numpy")] > 0
+    assert len(set(counts.values())) == 1, counts
 
 
 def test_trace_sampling_deterministic():
@@ -336,3 +428,45 @@ def test_faulted_service_counters_sum_to_submitted():
     # and the same numbers survive the Prometheus round trip
     parsed = parse_prometheus(to_prometheus())
     assert parsed['gnnpe_service_request_seconds_count{status="error"}'] >= 1
+
+
+def test_service_records_queue_wait_handoff_and_idle():
+    """One queue wait per request, both hand-off legs per tick, and idle
+    time while nothing is queued — untraced, from the registry alone."""
+    g = _base_graph()
+    eng = _engine(g)
+    qs = _queries(g, n=4)
+    svc = MatchService(eng, ServiceConfig(
+        max_batch=1, schedule="fifo", idle_tick_s=0.02, cache_fastpath=False,
+        trace_rate=0.0,
+    ))
+    names = {
+        "queue": ("gnnpe_service_queue_wait_seconds", {}),
+        "to_engine": ("gnnpe_service_handoff_seconds", {"leg": "to_engine"}),
+        "to_loop": ("gnnpe_service_handoff_seconds", {"leg": "to_loop"}),
+        "idle": ("gnnpe_service_idle_seconds", {}),
+    }
+    before = {k: (_hist(n, "count", **lb), _hist(n, **lb)) for k, (n, lb) in names.items()}
+
+    async def run():
+        await svc.start()
+        await asyncio.sleep(0.05)  # nothing queued: the loop idles
+        for q in qs:  # one at a time, so each request is its own tick
+            assert (await svc.submit(q)[1]).ok
+        await svc.stop()
+
+    old_rate = TRACER.trace_rate
+    try:
+        asyncio.run(run())
+    finally:
+        TRACER.trace_rate = old_rate
+    delta = {
+        k: (_hist(n, "count", **lb) - before[k][0], _hist(n, **lb) - before[k][1])
+        for k, (n, lb) in names.items()
+    }
+    n_ticks = len(svc.tick_stats())
+    assert n_ticks == len(qs)
+    assert delta["queue"][0] == len(qs) and delta["queue"][1] >= 0
+    assert delta["to_engine"][0] == delta["to_loop"][0] == n_ticks
+    assert delta["to_engine"][1] > 0 and delta["to_loop"][1] > 0
+    assert delta["idle"][0] >= 1 and delta["idle"][1] > 0

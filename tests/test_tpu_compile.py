@@ -1,4 +1,5 @@
-"""Compile the main-path Pallas kernels for a described TPU v5e.
+"""Compile the main-path Pallas kernels, and the stacked probe's mask and
+cell programs, for a described TPU v5e.
 
 The TPU compiler is installed with jax and compiles for a chip that is
 described, not attached, so tiling, layout and VMEM refusals surface here
@@ -108,3 +109,42 @@ def test_injectivity_mask_compiles(one_chip, T):
         injectivity_mask_pallas, one_chip, [(Tp, co_p), (Tp, cn_p)], dtype=jnp.int32,
         n_new=3, block_t=block_t,
     )
+
+
+def test_stacked_probe_mask_and_cell_programs_compile(one_chip):
+    """The stacked probe's mask program (with the per-slot funnel sums the
+    host reads in its first wait) and its cell expansion, at the served
+    cell's scale: 100 partition slots, queries bucketed to 8, ~320 leaf
+    blocks of 8 groups each."""
+    from repro.core import GnnPeConfig, GnnPeEngine
+    from repro.graphs import erdos_renyi
+
+    g = erdos_renyi(300, avg_degree=4, n_labels=5, seed=1)
+    probe = GnnPeEngine(GnnPeConfig(
+        n_partitions=2, encoder="monotone", n_multi=2, emb_dim=2, index_kind="grouped",
+        group_size=16, block_size=128, probe_impl="stacked", seed=1,
+    )).build(g).stacked_probe()
+    st = probe.stacked
+    S, Qp, n_lv = 100, 8, len(st.level_hi)
+    widths = [320 // 16 ** (n_lv - 1 - i) or 1 for i in range(n_lv)]
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    levels = tuple(
+        tuple(sds((S, w, x.shape[2])) for w, x in zip(widths, arrs))
+        for arrs in (st.level_hi, st.level_lo0, st.level_hi0)
+    )
+    G = widths[-1] * st.groups.gpb
+    groups = (sds((S, G, st.groups.hi.shape[2])), sds((S, G, st.groups.lo0.shape[2])),
+              sds((S, G, st.groups.hi0.shape[2])), sds((S, widths[-1]), jnp.int32))
+    masks = probe._mask_fn(True, 1e-6).lower(
+        levels, groups, sds((S, Qp, st.level_hi[0].shape[2])),
+        sds((S, Qp, st.level_lo0[0].shape[2])),
+    ).compile()
+    assert masks.as_text().startswith("HloModule jit_slot_fn")
+    cells = probe._cells_fn(True, 4096).lower(
+        sds((S, 1, G), jnp.bool_), 10, sds((S,), jnp.int32),
+        sds((S, G), jnp.int32), sds((S, G), jnp.int32),
+    ).compile()
+    assert cells.as_text().startswith("HloModule jit_cells")
