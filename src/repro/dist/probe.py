@@ -40,7 +40,22 @@ from ..core.stacked import StackedIndex, build_stacked, restack_slot, stacked_ma
 from ..obs import trace as obs_trace
 from ..shapes import pow2_at_least
 
-__all__ = ["StackedProbe"]
+__all__ = ["StackedProbe", "survivor_cells"]
+
+
+def survivor_cells(mask, cap: int):
+    """Indices of ``mask``'s first ``cap`` set cells in row-major order,
+    padded with 0 — what ``jnp.nonzero(mask, size=cap, fill_value=0)``
+    returns.  The ``k``-th set cell is the first position whose inclusive
+    count reaches ``k``: one cumsum, then a binary search of ``cap``
+    lanes, ~log2(mask.size) gathers each.  ``nonzero`` instead runs a
+    ``bincount`` — a scatter of one update per mask cell into ``cap``
+    bins, which a TPU serialises."""
+    cs = jnp.cumsum(mask.reshape(-1), dtype=jnp.int32)
+    k = jnp.arange(1, cap + 1, dtype=jnp.int32)
+    flat = jnp.searchsorted(cs, k, side="left", method="scan")
+    flat = jnp.where(k <= cs[-1], flat, 0)
+    return jnp.unravel_index(flat, mask.shape)
 
 
 class StackedProbe:
@@ -447,7 +462,7 @@ class StackedProbe:
             bs = self.stacked.block_size
 
             def cells(mask, n_cells, n_paths, g_start, g_count):
-                pi, qi, ci = jnp.nonzero(mask, size=cell_cap, fill_value=0)
+                pi, qi, ci = survivor_cells(mask, cell_cap)
                 cvalid = jnp.arange(cell_cap) < n_cells
                 if use_groups:
                     starts = g_start[pi, ci]

@@ -8,6 +8,8 @@ import subprocess
 import sys
 import textwrap
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -27,7 +29,7 @@ from repro.core.grouping import attach_groups
 from repro.core.index import hash_labels
 from repro.core.matcher import _lex_keys, _unique_rows
 from repro.core.stacked import stacked_masks_ref
-from repro.dist.probe import StackedProbe
+from repro.dist.probe import StackedProbe, survivor_cells
 from repro.graphs import erdos_renyi, random_connected_query
 
 
@@ -120,6 +122,80 @@ def test_stacked_probe_equals_loop_sweep(kind, quantize):
                         assert got[i][qi].dtype == np.int64
                         if indexes[i].n_paths:
                             assert ref_stats[i][qi] == got_stats[i][qi]
+        # device-resident assembly: per probe, the path vertices of the
+        # stacked probe's rows (tombstones dropped) in slot order, and the
+        # surviving row count of every partition.  Queries are scaled-down
+        # copies of indexed paths, so many cells and rows survive.
+        src = indexes[0]
+        r = rng.choice(src.n_paths, 8, replace=False)
+        scale = rng.random((8, 1)).astype(np.float32)
+        dq_emb = np.broadcast_to(src.emb[r] * scale, (len(indexes),) + src.emb[r].shape)
+        dq_emb0 = np.broadcast_to(src.emb0[r], (len(indexes),) + src.emb0[r].shape)
+        dq_multi = (
+            np.stack([np.broadcast_to(m[r] * scale, dq_emb.shape) for m in src.emb_multi])
+            if n_gnn else None
+        )
+        dqh = src.label_hash[r] if quantize else None
+        rows_ref = probe.probe(
+            dq_emb, dq_emb0, dq_multi, q_label_hash=dqh, use_groups=use_groups,
+            use_pallas=False,
+        )
+        slot_order = np.argsort(probe.stacked.slot_of)
+        p_max = probe.stacked.emb_cat.shape[1]
+        for live_mask in [None, rng.random((probe.stacked.n_slots, p_max)) < 0.7]:
+            per_b, part_counts = probe.probe_device(
+                dq_emb, dq_emb0, dq_multi, q_label_hash=dqh,
+                use_groups=use_groups, live_mask=live_mask,
+            )
+            for qi, (verts, n) in enumerate(per_b):
+                want = []
+                for i in slot_order:
+                    rows = rows_ref[i][qi]
+                    if live_mask is not None:
+                        rows = rows[live_mask[probe.stacked.slot_of[i], rows]]
+                    assert part_counts[i, qi] == rows.size
+                    want.append(indexes[i].paths[rows].astype(np.int32))
+                want = np.concatenate(want)
+                assert n == len(want)
+                np.testing.assert_array_equal(np.asarray(verts)[:n], want)
+
+
+@pytest.mark.parametrize(
+    "shape,cap,fill",
+    [
+        ((4, 1, 37), 16, "empty"),
+        ((4, 1, 37), 16, "one"),
+        ((100, 1, 2552), 512, "sparse"),
+        ((6, 2, 9), 32, "exactly_cap"),
+        ((5, 1, 40), 64, "one_slot_full"),
+        ((100, 4, 255), 1024, "sparse"),
+    ],
+    ids=["empty", "one_cell", "sparse", "n_cells_eq_cap", "one_slot_full", "multi_probe"],
+)
+def test_survivor_cells_equal_nonzero(shape, cap, fill):
+    """The cells program's survivor compaction gives ``jnp.nonzero``'s
+    (slot, probe, cell) indices, row-major, on the first ``n_cells``
+    entries and 0 on the padding after them."""
+    rng = np.random.default_rng(cap)
+    mask = np.zeros(shape, bool)
+    if fill == "one":
+        mask[2, 0, 30] = True
+    elif fill == "sparse":
+        mask.flat[rng.choice(mask.size, cap // 2 + 7, replace=False)] = True
+    elif fill == "exactly_cap":
+        mask.flat[rng.choice(mask.size, cap, replace=False)] = True
+    elif fill == "one_slot_full":
+        mask[3] = True
+    n_cells = int(mask.sum())
+    assert n_cells <= cap
+    got = jax.jit(survivor_cells, static_argnums=1)(jnp.asarray(mask), cap)
+    want = jnp.nonzero(jnp.asarray(mask), size=cap, fill_value=0)
+    assert len(got) == len(want) == 3
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.shape == (cap,) and g.dtype == w.dtype
+        np.testing.assert_array_equal(g[:n_cells], w[:n_cells])
+        assert not g[n_cells:].any()
 
 
 def test_stacked_levels_and_masks_reference():

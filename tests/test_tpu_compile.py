@@ -12,7 +12,9 @@ tests/test_device_join.py.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,8 +145,18 @@ def test_stacked_probe_mask_and_cell_programs_compile(one_chip):
         sds((S, Qp, st.level_lo0[0].shape[2])),
     ).compile()
     assert masks.as_text().startswith("HloModule jit_slot_fn")
-    cells = probe._cells_fn(True, 4096).lower(
+    cell_cap = 4096
+    cells = probe._cells_fn(True, cell_cap).lower(
         sds((S, 1, G), jnp.bool_), 10, sds((S,), jnp.int32),
         sds((S, G), jnp.int32), sds((S, G), jnp.int32),
-    ).compile()
-    assert cells.as_text().startswith("HloModule jit_cells")
+    )
+    # the survivor compaction scatters nothing per mask cell (jnp.nonzero's
+    # bincount did: one update per cell, serialised on the chip); the one
+    # scatter left is the per-slot pair sum, one update per cell slot
+    scatters = re.findall(
+        r'"stablehlo\.scatter".*?\}\) : \(([^)]*)\) ->', cells.as_text(), re.S
+    )
+    updates = [re.findall(r"tensor<([^>]*)>", sig)[2] for sig in scatters]
+    sizes = [math.prod(int(d) for d in u.split("x")[:-1]) for u in updates]
+    assert cell_cap in sizes and S * G not in sizes, sizes
+    assert cells.compile().as_text().startswith("HloModule jit_cells")
