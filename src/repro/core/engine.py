@@ -37,6 +37,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +94,35 @@ _M_FUNNEL = _OBS.counter(
     "Cumulative pruning-funnel counts (candidates surviving each level)",
     labels=("stage",),
 )
+_M_EMBED_ROWS = _OBS.counter(
+    "gnnpe_engine_embed_rows_total",
+    "Query star rows embedded via match_many, real and bucket padding",
+    labels=("kind",),
+)
+
+
+@partial(jax.jit, static_argnums=0)
+def _embed_query_stars(enc, main, multi, centers, leaf_labels, leaf_mask, relabeled, overflow):
+    """Every partition's query-star embeddings in one program.
+
+    ``main`` and each ``multi[i]`` are partition-stacked encoder params;
+    ``relabeled[i]`` is ``(centers, leaf_labels)`` under label
+    permutation i.  Returns float32 (2 + n_multi, m, n, d): the stars,
+    the isolated labels, then each multi-GNN's relabelled stars.
+    Overflow stars (degree > θ) embed to 0 in the star planes so they
+    prune nothing (query-side safety, as ``_query_node_embeddings``)."""
+    drop = overflow[None, :, None]
+
+    def stars(params, c, leaves):
+        o = jax.vmap(lambda p: enc.embed_stars(p, c, leaves, leaf_mask))(params)
+        return jnp.where(drop, 0.0, o.astype(jnp.float32))
+
+    planes = [
+        stars(main, centers, leaf_labels),
+        jax.vmap(lambda p: enc.embed_isolated(p, centers))(main).astype(jnp.float32),
+    ]
+    planes += [stars(p, c, leaves) for p, (c, leaves) in zip(multi, relabeled)]
+    return jnp.stack(planes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1390,28 +1420,35 @@ class GnnPeEngine:
     def _query_node_embeddings_many(self, queries: list):
         """Embed ALL queries' stars with every partition's GNNs.
 
-        Star tensors concatenate across queries AND the partition models
-        stack for ``jax.vmap``, so the whole (query batch × partition)
-        embedding grid is 2 + n_multi dispatches total (instead of
-        Q × m × (2+n)).  Returns ``(cat, spans)``: ``cat[mi] = (o, o0,
-        o_multi)`` concatenated over queries, with query ``qi``'s rows at
+        Star tensors concatenate across queries, pad to a power-of-two
+        row bucket, and the partition models stack, so the whole (query
+        batch × partition) embedding grid is one dispatch of
+        ``_embed_query_stars`` and one read (instead of Q × m × (2+n)
+        calls), and a tick compiles one program per bucket.  Star
+        embedding is row-independent, so the padding changes no real
+        row.  Returns ``(cat, spans)``: ``cat[mi] = (o, o0, o_multi)``
+        concatenated over queries, with query ``qi``'s rows at
         ``spans[qi]:spans[qi+1]`` — row-identical to
         ``_query_node_embeddings``.
         """
         cfg = self.cfg
-        enc = self.encoder
         with obs_trace.step("embed", "stars"):
             star_list = [
                 build_star_tensors(q, np.arange(q.n_vertices), cfg.theta) for q in queries
             ]
             sizes = [q.n_vertices for q in queries]
             spans = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
-            centers = np.concatenate([s.center_labels for s in star_list])
-            leaf_labels = np.concatenate([s.leaf_labels for s in star_list])
-            leaf_mask = np.concatenate([s.leaf_mask for s in star_list])
-            overflow = np.concatenate([s.overflow for s in star_list])
             if not self.models:
                 return [], spans
+            n = int(spans[-1])
+            rows = pow2_at_least(n, floor=1)  # a lone 4-vertex query pads nothing
+            _M_EMBED_ROWS.labels(kind="real").inc(n)
+            _M_EMBED_ROWS.labels(kind="pad").inc(rows - n)
+            # padded rows: centre label 0, no leaves, not overflow
+            centers, leaf_labels, leaf_mask, overflow = (
+                pad_rows(np.concatenate([getattr(s, f) for s in star_list]), rows)
+                for f in ("center_labels", "leaf_labels", "leaf_mask", "overflow")
+            )
             relabeled = [
                 (
                     self.label_perms[i][centers].astype(np.int32),
@@ -1420,27 +1457,13 @@ class GnnPeEngine:
                 for i in range(cfg.n_multi)
             ]
         main, multi = self._stacked_model_params()
-        # every encoder call dispatches before the first result is read
         with obs_trace.step("embed", "encode"):
-            outs = [
-                jax.vmap(lambda p: enc.embed_stars(p, centers, leaf_labels, leaf_mask))(main),
-                jax.vmap(lambda p: enc.embed_isolated(p, centers))(main),
-            ] + [
-                jax.vmap(lambda p, c=c, l=l: enc.embed_stars(p, c, l, leaf_mask))(multi[i])
-                for i, (c, l) in enumerate(relabeled)
-            ]
+            out = _embed_query_stars(
+                self.encoder, main, multi, centers, leaf_labels, leaf_mask, relabeled, overflow
+            )
         with obs_trace.wait("embed"):
-            outs = jax.device_get(outs)
-        o_all = outs[0].astype(np.float32)  # (m, n, d)
-        o0_all = outs[1].astype(np.float32)
-        o_all[:, overflow] = 0.0
-        om_all = np.zeros((cfg.n_multi, len(self.models), centers.shape[0], cfg.emb_dim), np.float32)
-        for i in range(cfg.n_multi):
-            om_all[i] = outs[2 + i]
-            om_all[i][:, overflow] = 0.0
-        cat = [
-            (o_all[mi], o0_all[mi], om_all[:, mi]) for mi in range(len(self.models))
-        ]
+            out = jax.device_get(out)[:, :, :n]
+        cat = [(out[0, mi], out[1, mi], out[2:, mi]) for mi in range(len(self.models))]
         return cat, spans
 
     def _stacked_live_mask(self, probe) -> np.ndarray | None:

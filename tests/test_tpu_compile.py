@@ -1,5 +1,6 @@
-"""Compile the main-path Pallas kernels, and the stacked probe's mask and
-cell programs, for a described TPU v5e.
+"""Compile the main-path Pallas kernels, the stacked probe's mask and
+cell programs, and the query-side encoder program, for a described TPU
+v5e.
 
 The TPU compiler is installed with jax and compiles for a chip that is
 described, not attached, so tiling, layout and VMEM refusals surface here
@@ -7,8 +8,8 @@ instead of on the chip.  Each case compiles a kernel's jitted entry point
 as its ops wrapper calls it: at the padded shapes and block size the
 wrapper's layout helper picks for the engine's widths, with
 ``interpret=False``, as a program of its own.  Nothing runs: results are
-held to the references by tests/test_kernels.py and
-tests/test_device_join.py.
+held to the references by tests/test_kernels.py,
+tests/test_device_join.py and tests/test_query_embed.py.
 """
 from __future__ import annotations
 
@@ -160,3 +161,28 @@ def test_stacked_probe_mask_and_cell_programs_compile(one_chip):
     sizes = [math.prod(int(d) for d in u.split("x")[:-1]) for u in updates]
     assert cell_cap in sizes and S * G not in sizes, sizes
     assert cells.compile().as_text().startswith("HloModule jit_cells")
+
+
+def test_query_embed_program_compiles(one_chip):
+    """The query-side encoder program of one tick, at the served cell's
+    scale: the monotone encoder over 15 labels, 100 partition models,
+    n_multi 2, θ 10, one 8-row star bucket."""
+    from repro.core.encoder import EncoderConfig, make_encoder
+    from repro.core.engine import _embed_query_stars
+
+    m, rows, theta, n_multi = 100, 8, 10, 2
+    enc = make_encoder(EncoderConfig(n_labels=15, out_dim=2, theta=theta, kind="monotone"))
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds((m,) + x.shape, x.dtype), jax.eval_shape(enc.init, jax.random.key(0))
+    )
+    centers, leaves = sds((rows,), jnp.int32), sds((rows, theta), jnp.int32)
+    lowered = _embed_query_stars.lower(
+        enc, params, [params] * n_multi, centers, leaves, sds((rows, theta), jnp.bool_),
+        [(centers, leaves)] * n_multi, sds((rows,), jnp.bool_),
+    )
+    assert lowered.out_info.shape == (2 + n_multi, m, rows, 2)
+    assert lowered.compile().as_text().startswith("HloModule jit__embed_query_stars")
