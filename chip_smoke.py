@@ -9,15 +9,19 @@ Newman–Watts–Strogatz graph, indexes it with ``GnnPeEngine`` (monotone
 encoder, grouped index, l=2, ~1,000 vertices per partition), and serves a
 seeded request stream through ``MatchService`` twice:
 
-  (a) the default path — loop probe, NumPy join, and the Pallas leaf scan
-      the engine picks by itself on a TPU;
-  (b) the device-resident path — stacked probe, device join.
+  (a) the stacked probe's host leaf stage, the NumPy join, and the Pallas
+      leaf scan the engine picks by itself on a TPU;
+  (b) the device-resident path — the stacked probe's device leaf stage,
+      device join.
 
 Each pass serves 32 queries (5, 6 and 8 vertices), one update epoch (a few
 edge inserts and deletes, which puts the delta buffer's probe on the path)
 and 8 more queries.  Every response must be ``ok`` and every match set must
-equal the engine's NumPy reference (loop probe, NumPy join, no Pallas) at the
-same epoch.  The last line of standard output is one JSON object with the
+equal the engine's NumPy reference (NumPy leaf scan, NumPy join) at the same
+epoch.  That reference shares the stacked probe's group and MBR descent
+with both passes, so four queries of each phase, spread over its sizes, are
+also held to ``vf2_match`` on the graph at that epoch, which shares no code
+with the engine (about 1.6 s a query on the host).  The last line of standard output is one JSON object with the
 device JAX reports; it is printed only when every check passed.  Without a
 TPU the script exits non-zero before building anything.
 
@@ -46,6 +50,7 @@ VERTICES_PER_PARTITION = 1000
 QUERY_SIZES = (5, 6, 8)
 N_BEFORE = 32  # queries before the update epoch; a quarter as many after it
 N_EDITS = 4  # edge inserts and deletes in the update epoch
+VF2_PER_PHASE = 4  # queries of each phase also held to vf2_match
 # a smoke run must end within 1,200 s; no single phase waits longer
 RUN_LIMIT_S = 1200.0
 
@@ -111,7 +116,6 @@ def engine_config(n_vertices: int, seed: int):
         path_length=2,
         emb_dim=2,
         n_multi=2,
-        probe_impl="stacked",
         n_partitions=max(n_vertices // VERTICES_PER_PARTITION, 1),
         seed=seed,
     )
@@ -167,22 +171,22 @@ def sample_update(g, seed: int):
 
 
 def reference(engine, queries) -> list:
-    """The engine's NumPy oracle at its current epoch."""
+    """The engine's NumPy reference at its current epoch: the stacked
+    probe's host leaf stage with the NumPy leaf scan, and the NumPy join."""
     cfg = engine.cfg
     engine.cfg = dataclasses.replace(cfg, use_pallas_scan=False)
     try:
-        return engine.match_many(queries, probe_impl="loop", join_impl="numpy")
+        return engine.match_many(queries, join_impl="numpy")
     finally:
         engine.cfg = cfg
 
 
-async def _serve(engine, queries, update, probe_impl, join_impl):
+async def _serve(engine, queries, update, join_impl):
     from repro.serve.service import MatchService, ServiceConfig
 
     svc = MatchService(
         engine,
         ServiceConfig(
-            probe_impl=probe_impl,
             join_impl=join_impl,
             attempt_timeout_s=RUN_LIMIT_S,  # first ticks compile on the chip
         ),
@@ -197,10 +201,12 @@ async def _serve(engine, queries, update, probe_impl, join_impl):
         await svc.stop(drain=False)
 
 
-def serve_and_check(engine, queries, update, probe_impl, join_impl, tag, compiles) -> list:
+def serve_and_check(engine, queries, update, join_impl, tag, compiles) -> list:
     """Serve one phase through MatchService; check status and exactness."""
+    from repro.core import vf2_match
+
     t0 = time.perf_counter()
-    resps = asyncio.run(_serve(engine, queries, update, probe_impl, join_impl))
+    resps = asyncio.run(_serve(engine, queries, update, join_impl))
     t_serve = time.perf_counter() - t0
     bad = [(r.request_id, r.status, r.reason) for r in resps if r.status != "ok"]
     if bad:
@@ -215,25 +221,34 @@ def serve_and_check(engine, queries, update, probe_impl, join_impl, tag, compile
                 f"{tag}: query {i} at epoch {engine.epoch}: {len(got)} matches, "
                 f"reference {len(want)}"
             )
+    t_ref = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    sample = range(0, len(queries), max(len(queries) // VF2_PER_PHASE, 1))[:VF2_PER_PHASE]
+    for i in sample:
+        want = vf2_match(engine.graph, queries[i])
+        if {tuple(m) for m in resps[i].matches} != set(want):
+            raise SmokeFailure(
+                f"{tag}: query {i} at epoch {engine.epoch}: {len(resps[i].matches)} "
+                f"matches, vf2_match {len(want)}"
+            )
     n_matches = sum(len(r.matches) for r in resps)
     print(
         f"{tag}: {len(resps)} ok at epoch {engine.epoch}, {n_matches} matches equal the "
-        f"reference; serve {t_serve:.1f} s ({served_compiles}), reference "
-        f"{time.perf_counter() - t0:.1f} s ({compiles.phase()})",
+        f"reference, queries {list(sample)} equal vf2_match; serve {t_serve:.1f} s "
+        f"({served_compiles}), reference {t_ref:.1f} s ({compiles.phase()}), "
+        f"vf2_match {time.perf_counter() - t0:.1f} s",
         flush=True,
     )
     return resps
 
 
-def run_pass(engine, queries, n_before, seed, probe_impl, join_impl, tag, compiles) -> list:
+def run_pass(engine, queries, n_before, seed, join_impl, tag, compiles) -> list:
     resps = serve_and_check(
-        engine, queries[:n_before], None, probe_impl, join_impl, f"{tag} before update",
-        compiles,
+        engine, queries[:n_before], None, join_impl, f"{tag} before update", compiles
     )
     update = sample_update(engine.graph, seed)
     resps += serve_and_check(
-        engine, queries[n_before:], update, probe_impl, join_impl, f"{tag} after update",
-        compiles,
+        engine, queries[n_before:], update, join_impl, f"{tag} after update", compiles
     )
     lat = sorted(r.latency_s for r in resps)
     print(f"{tag}: p50 latency {lat[len(lat) // 2] * 1e3:.1f} ms (smoke figure, not a benchmark)")
@@ -264,9 +279,7 @@ def main(argv=None) -> int:
     try:
         if args.chips == 1:
             calls0 = index_mod.PALLAS_SCAN_CALLS
-            run_pass(
-                engine, queries, n_before, args.seed + 1, "loop", "numpy", "pass (a)", compiles
-            )
+            run_pass(engine, queries, n_before, args.seed + 1, "numpy", "pass (a)", compiles)
             calls = index_mod.PALLAS_SCAN_CALLS - calls0
             print(f"pass (a): PALLAS_SCAN_CALLS +{calls}, largest scan "
                   f"T={index_mod.PALLAS_SCAN_MAX_PAIRS} pairs")
@@ -276,9 +289,7 @@ def main(argv=None) -> int:
                 raise SmokeFailure("pass (a): no scan spanned more than one 2048-pair block")
         probe = engine.stacked_probe()
         exp0 = probe.host_expansions
-        run_pass(
-            engine, queries, n_before, args.seed + 2, "stacked", "device", "pass (b)", compiles
-        )
+        run_pass(engine, queries, n_before, args.seed + 2, "device", "pass (b)", compiles)
         probe_now = engine.stacked_probe()
         exp = probe_now.host_expansions - (exp0 if probe_now is probe else 0)
         join_mesh = _join_mesh()

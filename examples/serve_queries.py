@@ -2,7 +2,7 @@
 GNN-PE index over a larger graph, then serve a stream of subgraph-
 matching requests through the batched MatchServer — every tick fuses up
 to ``--batch`` queries into one match_many pass (shared star embedding,
-one index probe + one leaf scan per partition) — reporting latency
+one stacked probe + one leaf scan over every partition) — reporting latency
 percentiles + throughput and verifying exactness on a sample.
 
 ``--update-every K`` turns the stream into a live mixed query/update
@@ -57,6 +57,7 @@ import hashlib
 import json
 import time
 
+import jax
 import numpy as np
 
 from repro.compile_cache import enable_compile_cache
@@ -306,11 +307,6 @@ def main():
     )
     ap.add_argument("--group-size", type=int, default=16)
     ap.add_argument(
-        "--probe-impl", choices=["loop", "stacked"], default="loop",
-        help="index traversal: per-partition Python loop, or the stacked-"
-        "tensor probe vmapped/sharded over the local devices",
-    )
-    ap.add_argument(
         "--join-impl", choices=["numpy", "device"], default="numpy",
         help="candidate join + refine: the host sort-merge join, or the "
         "jitted device merge-join pipeline (kernels/merge_join)",
@@ -384,18 +380,15 @@ def main():
         GnnPeConfig(
             encoder="monotone", n_partitions=max(args.n // 1000, 1), n_multi=2,
             index_kind=args.index_kind, group_size=args.group_size,
-            probe_impl=args.probe_impl, join_impl=args.join_impl,
+            join_impl=args.join_impl,
             cache=args.cache,
         )
     ).build(g)
-    if args.probe_impl == "stacked":
-        import jax
-
-        print(
-            f"[offline] stacked probe over {len(jax.local_devices())} device(s): "
-            f"{engine.offline_stats['stacked_bytes']/1e6:.1f} MB stacked tensors "
-            f"({engine.offline_stats['stacked_padding_frac']:.0%} padding)"
-        )
+    print(
+        f"[offline] stacked probe over {len(jax.local_devices())} device(s): "
+        f"{engine.offline_stats['stacked_bytes']/1e6:.1f} MB stacked tensors "
+        f"({engine.offline_stats['stacked_padding_frac']:.0%} padding)"
+    )
     grp = (
         f", {engine.offline_stats['n_groups']} groups"
         if args.index_kind == "grouped"

@@ -1,8 +1,8 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points (``chip_smoke.py``, ``examples/serve_queries.py``,
-``benchmarks/run.py``) call ``enable_compile_cache()`` before they
-compile anything; tests never do.  The rule:
+Entry points (``chip_smoke.py``, ``examples/serve_queries.py``) call
+``enable_compile_cache()`` before they compile anything; tests never
+do.  The rule:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set → JAX already reads it, and no other
   directory is set;

@@ -7,22 +7,21 @@ packed block indexes.
 Online:   cost-model query plan → per-partition query embeddings →
 index retrieval (Lemmas 4.1–4.4) → multi-way join → exact refinement.
 
-Batched hot path (§Perf D — default, ``online_impl="batched"``):
-``match_many`` drives a whole batch of queries through ONE fused pass
-per stage instead of Python loops over (query × partition × path):
+Online hot path (§Perf D): ``match_many`` drives a whole batch of
+queries through ONE fused pass per stage instead of Python loops over
+(query × partition × path):
 
-  1. star tensors of every query concatenate into one batch, so each
-     partition's GNNs embed all queries' vertices in one call;
-  2. every (query, plan-path) probe against a partition — including the
-     ``plan_weight="dr"`` cost-model probes, which are memoized and
-     reused by retrieval — stacks into one ``query_index_batch`` call:
-     level-synchronous MBR masks evaluated as one compare-reduce per
-     level, then one Pallas ``dominance_scan`` leaf scan for the batch;
-  3. join + vectorized refine (see matcher.py) per query.
+  1. star tensors of every query concatenate into one batch, so every
+     partition's GNNs embed all queries' vertices in one program;
+  2. every (query, plan-path) probe — including the ``plan_weight="dr"``
+     cost-model probes, which are memoized and reused by retrieval —
+     runs as one descent of the dense stacked-tensor index over every
+     partition (core/stacked.py + dist/probe.py), shard_map'd over the
+     local devices' ("part",) mesh;
+  3. join + vectorized refine (see matcher.py) per query, or one
+     batched device join for the tick (``join_impl="device"``).
 
-``online_impl="scalar"`` keeps the original per-(partition, path) loop
-as the exactness cross-check and the benchmark baseline
-(benchmarks/bench_online_batch.py measures one against the other).
+``match(q)`` is ``match_many([q])[0]``; there is no other online path.
 
 Live serving (§delta): ``apply_updates`` absorbs online edge/vertex
 insertions and deletions without an offline rebuild — affected paths
@@ -61,8 +60,6 @@ from .index import (
     PackedIndex,
     build_index,
     hash_labels,
-    query_index,
-    query_index_batch_multi,
 )
 from .matcher import match_from_candidates, match_from_candidates_many
 from .paths import concat_path_embeddings, enumerate_paths
@@ -153,15 +150,13 @@ class GnnPeConfig:
     plan_weight: str = "deg"
     induced: bool = False
     quantize_index: bool = False  # §Perf C1/C2: int8 + label-hash leaf sidecar
-    online_impl: str = "batched"  # "batched" (§Perf D) | "scalar" (baseline)
-    # index traversal: "loop" walks one PackedIndex per partition in
-    # Python; "stacked" probes the dense stacked-tensor index as one
-    # vmapped descent, shard_map'd over the local devices' ("part",)
-    # mesh (core/stacked.py + dist/probe.py) — identical match sets
-    probe_impl: str = "loop"
+    # the online path is the batched pipeline over the stacked probe;
+    # these two fields accept only those values and select nothing
+    online_impl: str = "batched"
+    probe_impl: str = "stacked"
     # candidate join + refine backend (core/matcher.py): "numpy" is the
     # host sort-merge join (the oracle); "device" drives the jitted
-    # kernels/merge_join pipeline — with probe_impl="stacked" the leaf
+    # kernels/merge_join pipeline — the stacked probe's leaf
     # member-expansion output feeds it without leaving the device.
     # Match SETS are identical (sort_matches order)
     join_impl: str = "numpy"
@@ -226,6 +221,10 @@ class QueryStats:
 
 class GnnPeEngine:
     def __init__(self, cfg: GnnPeConfig):
+        if cfg.online_impl != "batched":
+            raise ValueError(f"unknown online_impl {cfg.online_impl!r}; use 'batched'")
+        if cfg.probe_impl != "stacked":
+            raise ValueError(f"unknown probe_impl {cfg.probe_impl!r}; use 'stacked'")
         self.cfg = cfg
         self.graph: Graph | None = None
         self.partitioning: Partitioning | None = None
@@ -277,10 +276,6 @@ class GnnPeEngine:
         if cfg.index_kind not in ("path", "grouped"):
             raise ValueError(
                 f"unknown index_kind {cfg.index_kind!r}; use 'path' or 'grouped'"
-            )
-        if cfg.probe_impl not in ("loop", "stacked"):
-            raise ValueError(
-                f"unknown probe_impl {cfg.probe_impl!r}; use 'loop' or 'stacked'"
             )
         if cfg.join_impl not in ("numpy", "device"):
             raise ValueError(
@@ -426,7 +421,7 @@ class GnnPeEngine:
         self._plan_cache.clear()
         if self._result_cache is not None:
             self._result_cache.clear()
-        if cfg.probe_impl == "stacked" and self.models:
+        if self.models:
             self.stacked_probe()  # eager: pay stacking offline, report bytes
         return self
 
@@ -493,7 +488,7 @@ class GnnPeEngine:
             stacked probe scanned against this partition (0 until a
             stacked probe ran — placement then falls back to rows);
           * ``probe_rows``  — cumulative candidate rows this partition
-            served to joins (all probe impls, main + delta);
+            served to joins (main + delta);
           * ``delta_rows``/``tombstones`` — current delta pressure.
         """
         self._ensure_part_counters()
@@ -664,11 +659,11 @@ class GnnPeEngine:
         ``strategy="delta"`` (default) runs the incremental path: touched
         vertices re-embed under the frozen partition GNNs, affected paths
         land in per-partition delta buffers, dead main rows tombstone,
-        over-full partitions compact (re-sort/re-pack just themselves and,
-        for ``probe_impl="stacked"``, re-stack only their shard slot).
+        over-full partitions compact (re-sort/re-pack just themselves and
+        re-stack only their shard slot).
         ``strategy="rebuild"`` applies the same graph change but then
         re-embeds/re-enumerates/re-packs EVERY partition from scratch —
-        the offline baseline benchmarks/bench_updates.py measures against.
+        the offline baseline and the delta path's equivalence oracle.
         Matches after either strategy are identical at every epoch.
 
         ``compaction="defer"`` skips the inline re-pack: over-threshold
@@ -899,9 +894,9 @@ class GnnPeEngine:
         """From-scratch re-embed + re-enumerate + re-pack of EVERY
         partition with the frozen per-partition GNNs.
 
-        This is the offline baseline the delta path is measured against
-        (benchmarks/bench_updates.py) and the equivalence oracle of the
-        update property tests — a full ``build()`` would also re-train,
+        This is the offline baseline of the delta path and the
+        equivalence oracle of the update property tests — a full
+        ``build()`` would also re-train,
         and retrieval equality is only defined under frozen params.
         """
         assert self.graph is not None, "call build() first"
@@ -920,7 +915,7 @@ class GnnPeEngine:
         self.offline_stats["index_bytes"] = int(sum(m.index.nbytes() for m in self.models))
         self._stacked_probe = None
         self._subset_probes.clear()
-        if self.cfg.probe_impl == "stacked" and self.models:
+        if self.models:
             self.stacked_probe()
         return self
 
@@ -1045,7 +1040,7 @@ class GnnPeEngine:
         self._live_mask_cache = None
         self._stacked_probe = None
         self._subset_probes.clear()
-        if self.cfg.probe_impl == "stacked" and self.models:
+        if self.models:
             self.stacked_probe()
         return True
 
@@ -1056,7 +1051,6 @@ class GnnPeEngine:
         self,
         queries: list,
         index_kind: str | None = None,
-        probe_impl: str | None = None,
         join_impl: str | None = None,
     ) -> list:
         """``match_many`` with per-request fault quarantine.
@@ -1067,8 +1061,7 @@ class GnnPeEngine:
         bisection, so one malformed/poisoned query costs O(log batch)
         extra ``match_many`` calls while every other request still
         returns exactly what a fault-free batch would have produced
-        (per-query results are batch-independent by construction — see
-        ``match_many``'s equivalence contract with ``impl="scalar"``).
+        (per-query results are batch-independent by construction).
 
         Exceptions marked ``transient = True`` (serve/errors.py's
         ``TransientError``) are NOT bisected: the fault is about the
@@ -1076,7 +1069,7 @@ class GnnPeEngine:
         just be an unbudgeted immediate retry — the whole batch fails as
         ``(False, exc)`` and the caller's retry/backoff policy decides.
         """
-        kw = dict(index_kind=index_kind, probe_impl=probe_impl, join_impl=join_impl)
+        kw = dict(index_kind=index_kind, join_impl=join_impl)
         if not queries:
             return []
         try:
@@ -1135,7 +1128,9 @@ class GnnPeEngine:
     # ------------------------------------------------------------------
     def _query_node_embeddings(self, q: Graph, model: PartitionModel):
         """Embed query stars with partition j's GNNs (query-side safety:
-        overflow query vertices embed to 0⃗ so they prune nothing)."""
+        overflow query vertices embed to 0⃗ so they prune nothing).  The
+        per-model oracle that ``_query_node_embeddings_many`` is tested
+        against; the online path never calls it."""
         cfg = self.cfg
         enc = self.encoder
         stars = build_star_tensors(q, np.arange(q.n_vertices), cfg.theta)
@@ -1262,142 +1257,15 @@ class GnnPeEngine:
             )
         return self._deg_plan_cached(q)
 
-    def match(
-        self,
-        q: Graph,
-        return_stats: bool = False,
-        impl: str | None = None,
-        probe_impl: str | None = None,
-        join_impl: str | None = None,
-    ):
-        """Exact subgraph matching of query q (Alg. 3).
-
-        ``impl`` overrides ``cfg.online_impl``: "batched" routes through
-        ``match_many`` (the fused hot path); "scalar" runs the original
-        per-(partition, path) loop (cross-check / benchmark baseline).
-        ``probe_impl`` selects the index traversal ("loop" | "stacked");
-        ``join_impl`` the join/refine backend ("numpy" | "device").
-        """
-        impl = impl or self.cfg.online_impl
-        if impl == "batched":
-            out = self.match_many(
-                [q], return_stats=return_stats, probe_impl=probe_impl, join_impl=join_impl
-            )
-            if return_stats:
-                matches, stats = out
-                return matches[0], stats[0]
-            return out[0]
-        if impl != "scalar":
-            raise ValueError(f"unknown online impl {impl!r}; use 'batched' or 'scalar'")
-        return self._match_scalar(q, return_stats=return_stats, join_impl=join_impl)
-
-    def _match_scalar(self, q: Graph, return_stats: bool = False, join_impl: str | None = None):
-        assert self.graph is not None, "call build() first"
-        cfg = self.cfg
-        stats = QueryStats()
-        t0 = time.perf_counter()
-        # per-partition query embeddings (needed by both DR planning and retrieval)
-        q_embs = [self._query_node_embeddings(q, m) for m in self.models]
-        probe_memo: dict = {}
-        delta = self.delta
-
-        def _retrieve(mi: int, p: tuple):
-            """→ (live main rows, delta-buffer rows) for one (partition, path)."""
-            key = (mi, p)
-            if key in probe_memo:
-                return probe_memo[key]
-            model = self.models[mi]
-            pv = np.asarray(p, dtype=np.int64)
-            qo, qo0, qom = q_embs[mi]
-            q_emb = qo[pv].reshape(-1)
-            q_emb0 = qo0[pv].reshape(-1)
-            q_multi = qom[:, pv].reshape(cfg.n_multi, -1) if cfg.n_multi else None
-            qh = None
-            if cfg.quantize_index:
-                from .index import hash_labels
-
-                qh = int(hash_labels(q.labels[pv][None, :])[0])
-            rows = query_index(model.index, q_emb, q_emb0, q_multi, q_label_hash=qh)
-            rows = self._live_rows(mi, rows)
-            drows = np.zeros((0,), np.int64)
-            if delta is not None and delta.parts[mi].n_rows:
-                out = probe_delta_multi(
-                    [(
-                        delta.parts[mi],
-                        q_emb[None, :],
-                        q_emb0[None, :],
-                        q_multi[:, None, :] if q_multi is not None else None,
-                        np.asarray([qh]) if qh is not None else None,
-                    )],
-                    use_pallas=False,
-                )
-                drows = out[0][0]
-            probe_memo[key] = (rows, drows)
-            return rows, drows
-
-        weight_fn = None
-        if cfg.plan_weight == "dr":
-            # paper §5.1 alternative: w(p_q) = |DR(o(p_q))| — candidate counts
-            # from an index probe (memoized; reused by the retrieval below)
-            def weight_fn(p):
-                return float(
-                    sum(
-                        sum(r.size for r in _retrieve(mi, p))
-                        for mi in range(len(self.models))
-                        if (self.models[mi].index.n_paths or (delta is not None and delta.parts[mi].n_rows))
-                        and len(p) == self.models[mi].index.paths.shape[1]
-                    )
-                )
-
-        plan = self._plan_cached(q, weight_fn=weight_fn)
-        stats.plan = plan
-        # candidate retrieval per partition, per query path
-        candidates = [[] for _ in plan.paths]
-        total_paths = 0
-        for mi, model in enumerate(self.models):
-            dp = delta.parts[mi] if delta is not None else None
-            n_live = model.index.n_paths + (
-                dp.n_rows - dp.n_tombstones if dp is not None else 0
-            )
-            if n_live <= 0:
-                continue
-            total_paths += n_live
-            for pi, p in enumerate(plan.paths):
-                if len(p) != model.index.paths.shape[1]:
-                    continue  # length-mismatched fallback path
-                rows, drows = _retrieve(mi, p)
-                if rows.size:
-                    candidates[pi].append(model.index.paths[rows])
-                if drows.size:
-                    candidates[pi].append(dp.paths[drows])
-        cand_arrays = []
-        cand_total = 0
-        for pi, parts in enumerate(candidates):
-            if parts:
-                arr = np.concatenate(parts, axis=0)
-            else:
-                arr = np.zeros((0, len(plan.paths[pi])), np.int32)
-            cand_arrays.append(arr)
-            cand_total += arr.shape[0]
-            stats.n_candidates[plan.paths[pi]] = int(arr.shape[0])
-        stats.filter_time = time.perf_counter() - t0
-        stats.total_paths = total_paths * max(len(plan.paths), 1)
-        stats.candidate_paths = cand_total
-        stats.pruning_power = 1.0 - cand_total / max(stats.total_paths, 1)
-        # join + refine
-        t1 = time.perf_counter()
-        # per-path candidates are duplicate-free (partitions are root-
-        # disjoint; delta rows are disjoint from live main rows), so the
-        # join may skip its dedup sorts
-        matches = match_from_candidates(
-            self.graph, q, plan.paths, cand_arrays, induced=cfg.induced,
-            join_impl=join_impl or cfg.join_impl, assume_unique=True,
-        )
-        stats.join_time = time.perf_counter() - t1
-        stats.n_matches = len(matches)
+    def match(self, q: Graph, return_stats: bool = False, join_impl: str | None = None):
+        """Exact subgraph matching of query q (Alg. 3): ``match_many``
+        over a batch of one.  ``join_impl`` overrides the join/refine
+        backend ("numpy" | "device")."""
+        out = self.match_many([q], return_stats=return_stats, join_impl=join_impl)
         if return_stats:
-            return matches, stats
-        return matches
+            matches, stats = out
+            return matches[0], stats[0]
+        return out[0]
 
     # ------------------------------------------------------------------
     # Batched online matching (§Perf D): the fused multi-query hot path
@@ -1504,7 +1372,6 @@ class GnnPeEngine:
         memo: dict,
         use_groups: bool = False,
         stats_memo: dict | None = None,
-        probe_impl: str | None = None,
         delta_memo: dict | None = None,
         dev_memo: dict | None = None,
         dev_counts: dict | None = None,
@@ -1514,20 +1381,16 @@ class GnnPeEngine:
 
         ``requests`` is a list of (qi, path) pairs; results land in
         ``memo[(mi, qi, path)]`` — the same rows separate ``query_index``
-        calls would produce, from ONE ``query_index_batch_multi`` (and
-        hence one Pallas leaf scan) covering every partition.  Probe
-        embeddings assemble as a single gather over the concatenated
-        query-star embeddings (no per-request Python loop).
+        calls would produce, from ONE descent of the dense stacked-tensor
+        index (one vmapped/sharded pass over ALL partitions,
+        dist/probe.py).  Probe embeddings assemble as a single gather
+        over the concatenated query-star embeddings (no per-request
+        Python loop).
 
         ``use_groups`` routes the probe through the GNN-PGE two-level
         scan; when ``stats_memo`` is given, per-probe traversal stats
         land in ``stats_memo[(mi, qi, path)]`` (the grouped cost model
         reads ``surviving_groups`` from there).
-
-        ``probe_impl="stacked"`` traverses the dense stacked-tensor
-        index (one vmapped/sharded descent over ALL partitions,
-        dist/probe.py) instead of looping per-partition ``PackedIndex``
-        objects — memo entries are identical either way.
 
         With live updates pending (§delta), main-index results are
         filtered through the tombstone masks and the per-partition delta
@@ -1537,11 +1400,10 @@ class GnnPeEngine:
 
         ``parts`` (cluster tier) restricts the probe to those model
         indices: a host probes only the partitions placement assigned to
-        it — under ``probe_impl="stacked"`` via a host-scoped subset
-        stack (``_subset_probe``), never the device-assembly path (the
-        liveness mask and dev layout are full-stack-keyed).  Memo entries
-        for the covered partitions are identical to an unrestricted
-        probe's.
+        it via a host-scoped subset stack (``_subset_probe``), never the
+        device-assembly path (the liveness mask and dev layout are
+        full-stack-keyed).  Memo entries for the covered partitions are
+        identical to an unrestricted probe's.
         """
         cfg = self.cfg
         cat, spans = q_embs
@@ -1576,7 +1438,6 @@ class GnnPeEngine:
                 om[:, gidx].reshape(cfg.n_multi, B, -1) if cfg.n_multi else None,
             )
 
-        impl = probe_impl or cfg.probe_impl
         self._ensure_part_counters()
         part_list = (
             sorted(int(mi) for mi in parts)
@@ -1586,7 +1447,7 @@ class GnnPeEngine:
         # device assembly needs the full stack (liveness mask + layout
         # are keyed on it) — a parts-scoped probe takes the host path
         use_dev = dev_memo is not None and parts is None
-        if impl == "stacked" and part_list:
+        if part_list:
             # one vmapped (and device-sharded) descent over EVERY partition
             # — or, cluster-scoped, over just this host's owned ones
             L = self.models[0].index.paths.shape[1]
@@ -1647,36 +1508,6 @@ class GnnPeEngine:
                 self._part_leaf_pairs[np.asarray(mis, np.int64)] += (
                     probe.part_leaf_pairs - lp_before
                 )
-        else:
-            items = []
-            sels = []
-            for mi in part_list:
-                model = self.models[mi]
-                if model.index.n_paths == 0:
-                    continue
-                L = model.index.paths.shape[1]
-                if L not in layouts:
-                    continue
-                sel, gidx, qh = layouts[L]
-                q_emb, q_emb0, q_multi = query_tensors(mi, gidx, len(sel))
-                items.append((model.index, q_emb, q_emb0, q_multi, qh))
-                sels.append((mi, sel))
-            if items:
-                # one fused traversal + ONE fused leaf scan for every partition
-                out = query_index_batch_multi(
-                    items,
-                    use_pallas=use_pallas,
-                    use_groups=use_groups,
-                    return_stats=stats_memo is not None,
-                )
-                results, stats = out if stats_memo is not None else (out, None)
-                for ii, ((mi, sel), rows_list) in enumerate(zip(sels, results)):
-                    for b, (qi, p) in enumerate(sel):
-                        rows = self._live_rows(mi, rows_list[b])
-                        memo[(mi, qi, p)] = rows
-                        self._part_probe_rows[mi] += rows.size
-                        if stats_memo is not None:
-                            stats_memo[(mi, qi, p)] = stats[ii][b]
         # ---- delta buffers: brute (query, row) pairs, one fused scan ----
         if delta_memo is None or self.delta is None or not self.delta.any_rows():
             return
@@ -1710,7 +1541,6 @@ class GnnPeEngine:
         requests: list,
         parts: list | None = None,
         index_kind: str | None = None,
-        probe_impl: str | None = None,
         return_stats: bool = False,
     ):
         """Cluster scatter primitive (dist/cluster.py): probe ``requests``
@@ -1737,7 +1567,7 @@ class GnnPeEngine:
         self._probe_batch(
             list(requests), queries, q_embs, memo,
             use_groups=kind == "grouped", stats_memo=stats_memo,
-            probe_impl=probe_impl, delta_memo=delta_memo, parts=parts,
+            delta_memo=delta_memo, parts=parts,
         )
         out: dict = {}
         empty: dict = {}
@@ -1761,22 +1591,19 @@ class GnnPeEngine:
         queries: list,
         return_stats: bool = False,
         index_kind: str | None = None,
-        probe_impl: str | None = None,
         join_impl: str | None = None,
     ):
         """Exact subgraph matching for a batch of queries (fused Alg. 3).
 
-        Per-query results are identical to ``match(q, impl="scalar")``;
-        the filter stage runs as one fused pass per partition for the
-        whole batch (shared star embedding, batched traversal, one
-        Pallas leaf scan).  ``plan_weight="dr"`` cost-model probes join
-        the same batch and are reused by retrieval.
+        Per-query results are independent of the batch they ride in;
+        the filter stage runs as one fused pass over every partition for
+        the whole batch (shared star embedding, one stacked descent, one
+        leaf scan).  ``plan_weight="dr"`` cost-model probes join the
+        same batch and are reused by retrieval.
 
         ``index_kind`` overrides ``cfg.index_kind`` for the probe layer:
         a "grouped" engine keeps its per-path arrays, so both probe
-        kinds stay available for cross-checks and benchmarks.
-        ``probe_impl`` likewise overrides ``cfg.probe_impl`` ("loop" |
-        "stacked") — match sets are byte-identical between the two.
+        kinds stay available for cross-checks.
 
         With ``cfg.cache`` on, queries whose WL-canonical signature is
         cached (and not invalidated by updates) skip the pipeline: the
@@ -1789,9 +1616,6 @@ class GnnPeEngine:
         kind = index_kind or cfg.index_kind
         if kind not in ("path", "grouped"):
             raise ValueError(f"unknown index_kind {kind!r}; use 'path' or 'grouped'")
-        impl = probe_impl or cfg.probe_impl
-        if impl not in ("loop", "stacked"):
-            raise ValueError(f"unknown probe_impl {impl!r}; use 'loop' or 'stacked'")
         jimpl = join_impl or cfg.join_impl
         if jimpl not in ("numpy", "device"):
             raise ValueError(f"unknown join_impl {jimpl!r}; use 'numpy' or 'device'")
@@ -1801,7 +1625,7 @@ class GnnPeEngine:
         t_start = time.perf_counter()
         cache = self._result_cache
         if cache is None:
-            results, stats, _ = self._match_many_core(queries, kind, impl, jimpl)
+            results, stats, _ = self._match_many_core(queries, kind, jimpl)
             _M_QUERIES.inc(nq)
             _M_BATCH_S.observe(time.perf_counter() - t_start)
             return (results, stats) if return_stats else results
@@ -1836,7 +1660,7 @@ class GnnPeEngine:
         if miss:
             _M_RCACHE.labels(result="miss").inc(len(miss))
             sub_results, sub_stats, contributing = self._match_many_core(
-                [queries[qi] for qi in miss], kind, impl, jimpl
+                [queries[qi] for qi in miss], kind, jimpl
             )
             with obs_trace.span("cache_store", n_entries=len(miss)):
                 for k, qi in enumerate(miss):
@@ -1867,17 +1691,17 @@ class GnnPeEngine:
         _M_BATCH_S.observe(time.perf_counter() - t_start)
         return (results, stats) if return_stats else results
 
-    def _match_many_core(self, queries: list, kind: str, impl: str, join_impl: str = "numpy"):
+    def _match_many_core(self, queries: list, kind: str, join_impl: str = "numpy"):
         """The fused batch pipeline (no result cache).  Returns
         ``(results, stats, contributing)`` where ``contributing[qi]`` is
         the set of partition (model) indices that produced candidate
         rows — what the result cache scopes its invalidation on.
 
-        With ``join_impl="device"`` and the stacked probe, the probe
-        hands back device-resident candidate vertex arrays (``dev_memo``)
-        plus per-partition counts (``dev_counts``) — the join consumes
-        them without a host round-trip; delta-buffer rows (small by
-        construction) upload alongside.
+        With ``join_impl="device"``, the probe hands back device-resident
+        candidate vertex arrays (``dev_memo``) plus per-partition counts
+        (``dev_counts``) — the join consumes them without a host
+        round-trip; delta-buffer rows (small by construction) upload
+        alongside.
 
         Each stage opens through ``obs.trace.step`` (stage histogram,
         ``gnnpe.<stage>`` profiler annotation, span when traced).
@@ -1899,7 +1723,7 @@ class GnnPeEngine:
         delta_memo: dict = {}
         delta = self.delta
         n_models = len(self.models)
-        device_assembly = join_impl == "device" and impl == "stacked" and n_models > 0
+        device_assembly = join_impl == "device" and n_models > 0
         dev_memo: dict | None = {} if device_assembly else None
         dev_counts: dict = {}
         # ---- plans (dr probes ride the same batched pipeline) -----------
@@ -1921,7 +1745,7 @@ class GnnPeEngine:
                 if probe_reqs:
                     self._probe_batch(
                         probe_reqs, queries, q_embs, memo,
-                        use_groups=use_groups, stats_memo=stats_memo, probe_impl=impl,
+                        use_groups=use_groups, stats_memo=stats_memo,
                         delta_memo=delta_memo, dev_memo=dev_memo, dev_counts=dev_counts,
                     )
 
@@ -2006,7 +1830,7 @@ class GnnPeEngine:
         with obs_trace.step("probe", n_requests=len(todo)):
             if todo:
                 self._probe_batch(
-                    todo, queries, q_embs, memo, use_groups=use_groups, probe_impl=impl,
+                    todo, queries, q_embs, memo, use_groups=use_groups,
                     delta_memo=delta_memo, dev_memo=dev_memo, dev_counts=dev_counts,
                 )
         filter_time = time.perf_counter() - t0

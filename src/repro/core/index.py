@@ -19,8 +19,8 @@ and replace the *control structure*:
 
 Aggregates (MBR', MBR₀) are exactly the aR-tree "aggregate data" of §4.2.
 
-Batched hot path (§Perf D — this PR):  ``query_index_batch`` runs the
-whole online filter for a *batch* of Q query paths at once:
+Batched reference (§Perf D):  ``query_index_batch`` runs the whole
+online filter for a *batch* of Q query paths at once:
 
   1. level-synchronous masks — ONE (Q, blocks, D) compare-reduce per
      level for every query simultaneously, descending through the union
@@ -32,8 +32,10 @@ whole online filter for a *batch* of Q query paths at once:
      the pure-NumPy reference stays behind ``use_pallas=False`` and is
      bit-equal (tests/test_batched_online.py).
 
-The scalar ``query_index`` is retained unchanged as the exactness
-cross-check and benchmark baseline.
+The engine probes through the stacked-tensor index (core/stacked.py,
+dist/probe.py); ``query_index_batch``/``query_index_batch_multi`` are
+the per-partition reference it is held to bit for bit in the tests, and
+the scalar ``query_index`` is the reference of those.
 
 GNN-PGE two-level probe (§Perf E — this PR): with the
 ``PackedGroupIndex`` sidecar (core/grouping.py) attached,
@@ -77,12 +79,12 @@ def _count_pallas_scan(n_pairs: int) -> None:
     PALLAS_SCAN_MAX_PAIRS = max(PALLAS_SCAN_MAX_PAIRS, int(n_pairs))
 
 # (query, row) / (query, group) pairs issued by the batched probes since the
-# last reset — benchmarks/CI use these to prove the two-level grouped probe
-# issues measurably fewer leaf-level dominance comparisons (BENCH_grouped.json).
+# last reset — tests use these to prove the two-level grouped probe issues
+# measurably fewer leaf-level dominance comparisons (test_grouped_index.py).
 # Backed by the obs registry (thread-safe: the engine executor thread, the
 # compaction thread, and cluster host threads all probe concurrently);
 # ``PAIR_COUNTERS`` below is a dict-like read/write view kept for
-# compatibility with tests, benchmarks, and dist/placement cost feeds.
+# compatibility with tests and dist/placement cost feeds.
 _PAIR_METRIC = REGISTRY.counter(
     "gnnpe_probe_pairs_total",
     "Probe pairs issued since process start, by predicate level",
@@ -91,7 +93,7 @@ _PAIR_METRIC = REGISTRY.counter(
 _LEAF_PAIRS = _PAIR_METRIC.labels(kind="leaf_pairs")
 _GROUP_PAIRS = _PAIR_METRIC.labels(kind="group_pairs")
 # (query path, group) pairs that pass the group-MBR check: the funnel's
-# surviving_groups rung, counted by every grouped probe (loop and stacked)
+# surviving_groups rung, counted by every grouped probe (stacked and reference)
 _SURVIVING_GROUPS = _PAIR_METRIC.labels(kind="surviving_groups")
 _PAIR_CHILDREN = {"leaf_pairs": _LEAF_PAIRS, "group_pairs": _GROUP_PAIRS}
 
@@ -888,6 +890,8 @@ def query_index_batch(
     use_groups: bool = False,
 ):
     """Alg. 3 traversal for a BATCH of query paths — one pass per level.
+    A test reference: the engine's online path is the stacked probe
+    (dist/probe.py), which must return these rows bit for bit.
 
     Level-synchronous over the union frontier: at each level the blocks
     surviving for any query are expanded once, and a single (Q, blocks)
@@ -921,6 +925,9 @@ def query_index_batch_multi(
     use_groups: bool = False,
 ):
     """Batched traversal over SEVERAL indexes (partitions) at once.
+    The per-partition reference that ``StackedProbe.probe`` and
+    ``probe_device`` are held to bit for bit in the tests; the engine's
+    online path does not call it.
 
     ``items``: list of ``(index, q_emb, q_emb0, q_multi, q_label_hash)``
     — one entry per partition, each with its own (Q_i, D) query batch.

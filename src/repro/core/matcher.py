@@ -382,7 +382,7 @@ def match_from_candidates(
 # signature) joins as ONE device program per step: dispatch overhead
 # divides by the batch and XLA fuses across far larger loops.  The host
 # join cannot batch — this is where the device path earns its speedup on
-# join-heavy batches (benchmarks/bench_join.py).
+# join-heavy batches.
 # --------------------------------------------------------------------------
 
 

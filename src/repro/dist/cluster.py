@@ -574,13 +574,11 @@ class ClusterEngine:
             probed.update(todo)
         # ---- assembly: the single-process candidate order, exactly -------
         # host join: ascending mi, main rows then delta rows per partition
-        # (_match_many_core's loop).  device join + stacked probe: the
-        # engine's probe assembles mains on device in ascending SLOT
-        # order with every partition's delta rows appended after — so
-        # the coordinator mirrors that order for byte-identity there too.
-        device_assembly = (
-            cfg.join_impl == "device" and cfg.probe_impl == "stacked" and n_models > 0
-        )
+        # (_match_many_core's loop).  device join: the engine's probe
+        # assembles mains on device in ascending SLOT order with every
+        # partition's delta rows appended after — so the coordinator
+        # mirrors that order for byte-identity there too.
+        device_assembly = cfg.join_impl == "device" and n_models > 0
         if device_assembly:
             slot_of = eng.stacked_probe().stacked.slot_of
             main_order = sorted(range(n_models), key=lambda mi: int(slot_of[mi]))

@@ -16,15 +16,17 @@ partition tensors, shard_map'd over a ``("part",)`` device mesh.
      (vectorized on the stacked layout, no per-partition Python loop),
      ride the conservative int8 + label-hash pre-filter, and settle in
      one fused ``dominance_scan_pairs`` call (NumPy reference behind
-     ``use_pallas=False``) — exactly the loop probe's exact predicates,
-     so row sets are identical per (partition, query).
+     ``use_pallas=False``) — exactly the exact predicates of
+     ``query_index_batch_multi``, so row sets are identical per
+     (partition, query).
 
-Mask math matches ``query_index_batch_multi`` bit for bit: both compute
-float32 ``bound ± eps`` compares, and the synthesized/padded bounds are
-reject sentinels that never pass (see core/stacked.py).  The probe is a
-drop-in for the loop traversal — ``GnnPeEngine`` selects it with
-``probe_impl="stacked"`` — and ``PAIR_COUNTERS`` / per-query stats keep
-the loop probe's semantics so cost models and benches read identically.
+Mask math matches ``query_index_batch_multi`` — the per-partition
+reference traversal the tests hold this probe to — bit for bit: both
+compute float32 ``bound ± eps`` compares, and the synthesized/padded
+bounds are reject sentinels that never pass (see core/stacked.py).
+This is ``GnnPeEngine``'s only index probe; ``PAIR_COUNTERS`` /
+per-query stats keep the reference's semantics so cost models read
+identically.
 """
 
 from __future__ import annotations
@@ -268,7 +270,7 @@ class StackedProbe:
             results = [[] for _ in range(n_parts)]
             return (results, [[] for _ in range(n_parts)]) if return_stats else results
         if int(st.n_paths.sum()) == 0:
-            # every partition is empty (zero length-L paths): the loop probe
+            # every partition is empty (zero length-L paths): the reference
             # returns empty row sets, so the stacked probe must too — even
             # under use_groups, where no sidecar could have been stacked
             results = [
@@ -694,7 +696,8 @@ class StackedProbe:
         return per_b, part_counts, stats
 
     def _device_probe_stats(self, alive, gkeep, use_groups, Q):
-        """Per-(partition, probe) traversal stats, loop-probe semantics."""
+        """Per-(partition, probe) traversal stats, ``query_index_batch_multi``
+        semantics."""
         st = self.stacked
         alive_np = np.asarray(alive)
         scanned = alive_np.sum(axis=2)

@@ -11,9 +11,8 @@ One shared surface for every tier (core engine, delta, dist, serve):
   optional stdlib ``/metrics`` HTTP endpoint, and structured JSON event
   logging.
 
-The whole subsystem can be switched off with :func:`disable` (used by
-``benchmarks/bench_obs.py`` to prove the instrumentation overhead is
-within the CI gate); :func:`enable` turns it back on.
+The whole subsystem can be switched off with :func:`disable`;
+:func:`enable` turns it back on.
 """
 from __future__ import annotations
 

@@ -250,8 +250,8 @@ class ShardedResultCache:
         coordinator's tick check (read-side work, never cross-host).
 
     Collision-free update streams therefore evict with
-    ``remote_evictions == 0`` — asserted in tests/test_cluster.py and
-    gated in benchmarks/bench_cluster.py.  The key→shard directory is
+    ``remote_evictions == 0`` — asserted in tests/test_cluster.py.  The
+    key→shard directory is
     maintained on put and lazily pruned on get (shards drop entries
     internally via LRU/invalidation).
     """

@@ -1,4 +1,4 @@
-"""Fault injection for the serving tier (tests, chaos smoke, benchmarks).
+"""Fault injection for the serving tier (tests and the chaos smoke).
 
 ``FlakyEngine`` wraps a ``GnnPeEngine`` and misbehaves on schedule while
 delegating everything else untouched, so the exact same index serves a

@@ -91,10 +91,6 @@ class MatchServeConfig:
     # probe layer override per server ("path" | "grouped" | None = engine
     # config) — lets one engine serve both kinds for A/B comparison
     index_kind: str | None = None
-    # index traversal override ("loop" | "stacked" | None = engine config);
-    # "stacked" probes the dense stacked-tensor index, sharded over the
-    # local device mesh (dist/probe.py)
-    probe_impl: str | None = None
     # join/refine backend override ("numpy" | "device" | None = engine
     # config); "device" keeps candidate assembly on the accelerator
     # (core/matcher.py join_impl)
@@ -397,7 +393,6 @@ class MatchServer:
         tier's execution primitive."""
         kw = dict(
             index_kind=self.cfg.index_kind,
-            probe_impl=self.cfg.probe_impl,
             join_impl=self.cfg.join_impl,
         )
         t_tick = time.perf_counter()

@@ -141,8 +141,10 @@ class ServiceConfig:
     max_queue: int = 256  # global queued-request cap (admission SHEDs past it)
     # engine-layer overrides forwarded to the inner MatchServer
     index_kind: str | None = None
-    probe_impl: str | None = None
     join_impl: str | None = None
+    # None or "stacked" only, and forwarded nowhere: the engine has one
+    # probe; kept for configurations that still name it
+    probe_impl: str | None = None
     # scheduling: "deadline" ranks by plan_cost × remaining slack
     # (cheapest-and-most-urgent first); "cost" by plan_cost alone;
     # "fifo" by submission order
@@ -255,6 +257,8 @@ class MatchService:
                 f"unknown shed_policy {cfg.shed_policy!r}; "
                 "use 'reject-new' or 'drop-lowest-priority'"
             )
+        if cfg.probe_impl not in (None, "stacked"):
+            raise ValueError(f"unknown probe_impl {cfg.probe_impl!r}; use 'stacked'")
         self.engine = engine
         self.cfg = cfg
         self.admission = AdmissionController(admission or AdmissionConfig())
@@ -265,7 +269,6 @@ class MatchService:
             MatchServeConfig(
                 max_batch=cfg.max_batch,
                 index_kind=cfg.index_kind,
-                probe_impl=cfg.probe_impl,
                 join_impl=cfg.join_impl,
                 schedule="fifo",  # ordering is owned by the priority queue
                 max_updates_per_tick=cfg.max_updates_per_tick,

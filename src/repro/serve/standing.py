@@ -161,7 +161,6 @@ def _full_candidates(engine, q, plan):
         q_embs,
         memo,
         use_groups=cfg.index_kind == "grouped",
-        probe_impl=cfg.probe_impl,
         delta_memo=delta_memo,
     )
     delta = engine.delta

@@ -1,9 +1,11 @@
-"""§Perf D batched online path: exact equivalence with the scalar path,
-int8 grid-edge soundness, and proof the Pallas kernel runs on the
-engine's REAL query path (not just in kernel unit tests)."""
+"""§Perf D batched online path: exact equivalence with the scalar index
+traversal and with VF2, int8 grid-edge soundness, the engine's one
+online path, and proof the Pallas kernel runs on the engine's REAL
+query path (not just in kernel unit tests)."""
 import dataclasses
 
 import numpy as np
+import pytest
 
 import repro.core.index as index_mod
 from repro.core import GnnPeConfig, GnnPeEngine, vf2_match
@@ -17,6 +19,7 @@ from repro.core.index import (
 )
 from repro.graphs import erdos_renyi, newman_watts_strogatz, random_connected_query
 from repro.serve.match_server import MatchServeConfig, MatchServer
+from repro.serve.service import MatchService, ServiceConfig
 
 
 # ------------------------------------------------ index-level equivalence ---
@@ -114,7 +117,7 @@ def test_quantized_index_keeps_exact_grid_edge_match():
 
 def test_match_many_equals_scalar_property():
     """Property (seeded sweep over random graphs/queries): match_many ==
-    per-query scalar match == VF2 oracle, byte-identical match sets."""
+    the same query matched alone (byte-identical) == VF2 oracle."""
     for seed in range(4):
         rng = np.random.default_rng(seed)
         g = erdos_renyi(int(rng.integers(60, 140)), avg_degree=3.5, n_labels=int(rng.integers(3, 6)), seed=seed)
@@ -134,9 +137,25 @@ def test_match_many_equals_scalar_property():
             continue
         batched = eng.match_many(queries)
         for qi, q in enumerate(queries):
-            scalar = eng.match(q, impl="scalar")
-            assert batched[qi] == scalar, f"seed {seed} query {qi}"
-            assert set(scalar) == set(vf2_match(g, q)), f"seed {seed} query {qi}"
+            alone = eng.match(q)
+            assert batched[qi] == alone, f"seed {seed} query {qi}"
+            assert set(alone) == set(vf2_match(g, q)), f"seed {seed} query {qi}"
+
+
+@pytest.mark.parametrize(
+    "make,field,value",
+    [
+        (lambda eng, kw: GnnPeEngine(GnnPeConfig(**kw)), "probe_impl", "loop"),
+        (lambda eng, kw: GnnPeEngine(GnnPeConfig(**kw)), "online_impl", "scalar"),
+        (lambda eng, kw: MatchService(eng, ServiceConfig(**kw)), "probe_impl", "loop"),
+    ],
+    ids=["engine-probe", "engine-online", "service-probe"],
+)
+def test_removed_online_paths_rejected(make, field, value):
+    """The stacked, batched pipeline is the only online path: the config
+    fields kept for existing configuration files accept nothing else."""
+    with pytest.raises(ValueError, match=field):
+        make(GnnPeEngine(GnnPeConfig()), {field: value})
 
 
 def test_engine_real_path_invokes_pallas_kernel():

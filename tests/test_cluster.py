@@ -116,7 +116,7 @@ def test_partition_stats_surface():
     the documented keys; probe-work counters populate under the stacked
     impl and feed a placement that separates hot partitions."""
     g = _base_graph()
-    eng = _engine(g, probe_impl="stacked")
+    eng = _engine(g)
     stats = eng.partition_stats()
     assert len(stats) == len(eng.models)
     for s in stats:
@@ -136,11 +136,10 @@ def test_partition_stats_surface():
 @pytest.mark.parametrize(
     "overrides",
     [
-        dict(index_kind="path", probe_impl="loop", plan_weight="deg"),
-        dict(index_kind="path", probe_impl="stacked", plan_weight="dr"),
-        dict(index_kind="grouped", probe_impl="stacked", plan_weight="dr"),
-        dict(index_kind="path", probe_impl="stacked", plan_weight="deg",
-             join_impl="device"),
+        dict(index_kind="grouped", plan_weight="deg", join_impl="device"),
+        dict(index_kind="path", plan_weight="dr"),
+        dict(index_kind="grouped", plan_weight="dr"),
+        dict(index_kind="path", plan_weight="deg", join_impl="device"),
     ],
 )
 def test_cluster_matches_identical_to_single_process(overrides):
@@ -162,7 +161,7 @@ def test_cluster_matches_identical_to_single_process(overrides):
 
 def test_cluster_host_loss_rescatters_locally():
     g = _base_graph()
-    eng = _engine(g, probe_impl="stacked")
+    eng = _engine(g)
     queries = _queries(g)
     cl = ClusterEngine(eng, n_hosts=3)
     cl.apply_updates(_rand_update(np.random.default_rng(9), eng.graph))
@@ -182,7 +181,7 @@ def test_sharded_cache_locality_partition_local_stream():
     only on that partition's owner shard: remote_evictions == 0 on a
     collision-free partition-local stream."""
     g = _base_graph()
-    eng = _engine(g, probe_impl="stacked")
+    eng = _engine(g)
     queries = _queries(g, n=6)
     cl = ClusterEngine(eng, n_hosts=3, cache_capacity=64)
     first = cl.match_many(queries)
@@ -232,7 +231,7 @@ def test_sharded_cache_homing_and_placement():
 
 def test_blue_green_generation_swap_and_version_check():
     g = _base_graph()
-    eng = _engine(g, probe_impl="stacked")
+    eng = _engine(g)
     queries = _queries(g)
     cl = ClusterEngine(eng, n_hosts=2)
     rng = np.random.default_rng(5)
@@ -288,7 +287,7 @@ def test_hot_vertex_coalescing_identical_matches_fewer_epochs():
 
     def run(coalesce):
         srv = MatchServer(
-            _engine(g, probe_impl="stacked"),
+            _engine(g),
             MatchServeConfig(max_updates_per_tick=1, coalesce_hot=coalesce),
         )
         for u in updates:
@@ -308,7 +307,7 @@ def test_hot_vertex_coalescing_identical_matches_fewer_epochs():
 
 def test_coalescing_never_pulls_past_conflicts_or_vertex_adds():
     srv = MatchServer(
-        _engine(_base_graph(), probe_impl="stacked"),
+        _engine(_base_graph()),
         MatchServeConfig(max_updates_per_tick=1, coalesce_hot=True),
     )
     hub = GraphUpdate(add_edges=np.array([[0, 1]]))
@@ -330,7 +329,7 @@ def test_coalescing_never_pulls_past_conflicts_or_vertex_adds():
 
 def test_cluster_router_serves_through_cluster():
     g = _base_graph()
-    eng = _engine(g, probe_impl="stacked")
+    eng = _engine(g)
     queries = _queries(g)
     cl = ClusterEngine(eng, n_hosts=2, cache_capacity=32)
     rt = ClusterRouter(cl, max_batch=2)
@@ -340,7 +339,7 @@ def test_cluster_router_serves_through_cluster():
         rt.submit_update(u)
     rids = [rt.submit(q) for q in queries]
     rt.run_until_drained()
-    ref = _engine(g, probe_impl="stacked")
+    ref = _engine(g)
     ref.apply_updates(updates)
     assert [rt.finished[r] for r in rids] == ref.match_many(queries)
     st = rt.stats()
@@ -355,8 +354,8 @@ def test_exchange_host_probe_roundtrip_threaded():
     spanning a LocalHost and an ExchangeHost replica agrees with the
     single-process engine, before and after a delta epoch."""
     g = _base_graph()
-    eng = _engine(g, index_kind="grouped", probe_impl="stacked", plan_weight="dr")
-    replica = _engine(g, index_kind="grouped", probe_impl="stacked", plan_weight="dr")
+    eng = _engine(g, index_kind="grouped", plan_weight="dr")
+    replica = _engine(g, index_kind="grouped", plan_weight="dr")
     queries = _queries(g)
     with tempfile.TemporaryDirectory() as root:
         ex = DirExchange(root)
@@ -403,7 +402,7 @@ _WORKER = textwrap.dedent(
     boot = init_distributed(num_processes=2, process_id=1, coordinator_address=coord)
     g = erdos_renyi(150, avg_degree=3.5, n_labels=4, seed=5)
     cfg = GnnPeConfig(n_partitions=3, encoder="monotone", n_multi=1,
-                      block_size=32, group_size=4, seed=7, probe_impl="stacked")
+                      block_size=32, group_size=4, seed=7)
     eng = GnnPeEngine(cfg).build(g)
     n = serve_exchange_host(eng, 1, DirExchange(root), timeout=240.0)
     print("WORKER_OK", boot["mode"], n)
@@ -422,7 +421,7 @@ _COORD = textwrap.dedent(
     boot = init_distributed(num_processes=2, process_id=0, coordinator_address=coord)
     g = erdos_renyi(150, avg_degree=3.5, n_labels=4, seed=5)
     cfg = GnnPeConfig(n_partitions=3, encoder="monotone", n_multi=1,
-                      block_size=32, group_size=4, seed=7, probe_impl="stacked")
+                      block_size=32, group_size=4, seed=7)
     eng = GnnPeEngine(cfg).build(g)
     queries = []
     for s in range(4):

@@ -101,26 +101,27 @@ def test_l_hop_reach_and_paths_touching():
 
 
 @pytest.mark.parametrize(
-    "kind,impl,quantize,plan_weight",
+    "kind,quantize,plan_weight",
     [
-        ("path", "loop", False, "deg"),
-        ("path", "stacked", True, "deg"),
-        ("grouped", "loop", True, "dr"),
-        ("grouped", "stacked", False, "deg"),
+        ("path", False, "dr"),
+        ("path", True, "deg"),
+        ("grouped", True, "dr"),
+        ("grouped", False, "deg"),
     ],
 )
-def test_delta_equals_rebuild_property(kind, impl, quantize, plan_weight):
+def test_delta_equals_rebuild_property(kind, quantize, plan_weight):
     """Random insert/delete/vertex sequences + forced compactions: the
     delta engine's matches equal the from-scratch rebuild's at EVERY
-    epoch (and VF2's), for both index kinds and both probe impls."""
+    epoch (and VF2's), for both index kinds, with and without the int8
+    sidecar, under both plan weights."""
     g = _base_graph()
     # epoch 2 compacts (tiny threshold engaged via needs_compaction math):
     # run half the epochs with compaction off, half with it forced on
     eng_d, eng_r = _engines(
-        g, index_kind=kind, probe_impl=impl, quantize_index=quantize,
+        g, index_kind=kind, quantize_index=quantize,
         plan_weight=plan_weight, delta_compact_min=10**9,
     )
-    rng = np.random.default_rng(hash((kind, impl)) % 2**32)
+    rng = np.random.default_rng(hash((kind, quantize, plan_weight)) % 2**32)
     queries = _queries(g)
     for epoch in range(4):
         if epoch == 2:
@@ -141,14 +142,15 @@ def test_delta_equals_rebuild_property(kind, impl, quantize, plan_weight):
         mr = eng_r.match_many(queries)
         for qi, q in enumerate(queries):
             assert sorted(md[qi]) == sorted(mr[qi]), (
-                f"{kind}/{impl} epoch {epoch} q{qi}: delta != rebuild"
+                f"{kind}/{quantize}/{plan_weight} epoch {epoch} q{qi}: delta != rebuild"
             )
             assert set(md[qi]) == set(vf2_match(cur, q)), f"q{qi}: != VF2 oracle"
         if epoch >= 2:
             assert s["compacted"], "forced compaction threshold did not trigger"
-    # scalar impl agrees with the batched path under pending deltas
-    ms = eng_d.match(queries[0], impl="scalar")
+    # a batch of one agrees with the batch and with VF2 under pending deltas
+    ms = eng_d.match(queries[0])
     assert sorted(ms) == sorted(md[0])
+    assert set(ms) == set(vf2_match(eng_d.graph, queries[0]))
 
 
 def test_delta_buffers_probed_without_compaction():
@@ -174,10 +176,10 @@ def test_delta_buffers_probed_without_compaction():
 def test_elastic_restack_only_touches_compacted_slot():
     """Compaction under a live stacked probe rewrites ONLY the affected
     shard slot (the probe object survives) and padding stats stay
-    consistent; results remain loop-identical."""
+    consistent; results stay equal to VF2's."""
     g = _base_graph()
     eng, _ = _engines(
-        g, index_kind="grouped", quantize_index=True, probe_impl="stacked",
+        g, index_kind="grouped", quantize_index=True,
         delta_compact_min=8, delta_compact_frac=0.01,
     )
     probe = eng._stacked_probe
@@ -195,10 +197,9 @@ def test_elastic_restack_only_touches_compacted_slot():
             assert int(st.n_paths[st.slot_of].sum()) == sum(
                 m.index.n_paths for m in eng.models
             )
-        stacked = eng.match_many(queries, probe_impl="stacked")
-        loop = eng.match_many(queries, probe_impl="loop")
-        for a, b in zip(stacked, loop):
-            assert a == b
+        stacked = eng.match_many(queries)
+        for q, a in zip(queries, stacked):
+            assert set(a) == set(vf2_match(eng.graph, q))
     assert compacted_any
 
 
@@ -206,7 +207,7 @@ def test_stacked_leaf_pair_cap_identical_results():
     """The capacity-bounded (chunked) leaf member-expansion returns the
     same rows as the unbounded expansion."""
     g = _base_graph()
-    cfg = dict(index_kind="grouped", quantize_index=True, probe_impl="stacked")
+    cfg = dict(index_kind="grouped", quantize_index=True)
     eng, _ = _engines(g, **cfg)
     queries = _queries(g, n=4)
     big = eng.match_many(queries)
@@ -344,7 +345,7 @@ def test_dr_plan_cache_reuses_and_retires_on_update():
     assert eng._dr_plan_peek(q, 1) is not None, "dr plan not cached"
     plan_a = eng._dr_plan_peek(q, 1)
     m_a = eng.match(q)  # served with the cached plan
-    assert sorted(m_a) == sorted(eng.match(q, impl="scalar"))
+    assert set(m_a) == set(vf2_match(eng.graph, q))
     # an update changes the embedding fingerprint → cached dr plan retires
     e = eng.graph.edge_array()
     eng.apply_updates(GraphUpdate(remove_edges=e[:1]))

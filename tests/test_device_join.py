@@ -233,7 +233,7 @@ def test_edge_key_overflow_guard():
 
 
 # ---------------------------------------------------------------------------
-# engine-level identity: kinds × probe impls × delta states
+# engine-level identity: kinds × join impls × delta states
 # ---------------------------------------------------------------------------
 
 
@@ -247,7 +247,6 @@ def engine_and_queries():
             n_multi=1,
             index_kind="grouped",
             quantize_index=True,
-            probe_impl="stacked",
             train=TrainConfig(max_epochs=30),
         )
     ).build(g)
@@ -259,12 +258,13 @@ def engine_and_queries():
 
 def test_device_join_identity_sweep(engine_and_queries):
     eng, queries = engine_and_queries
-    base = eng.match_many(queries, index_kind="path", probe_impl="loop", join_impl="numpy")
+    base = eng.match_many(queries, index_kind="path", join_impl="numpy")
+    for q, b in zip(queries, base):
+        assert set(b) == set(vf2_match(eng.graph, q))
     for kind in ("path", "grouped"):
-        for pimpl in ("loop", "stacked"):
-            got = eng.match_many(queries, index_kind=kind, probe_impl=pimpl, join_impl="device")
-            for qi, (a, b) in enumerate(zip(got, base)):
-                assert sort_matches(a) == sort_matches(b), (kind, pimpl, qi)
+        got = eng.match_many(queries, index_kind=kind, join_impl="device")
+        for qi, (a, b) in enumerate(zip(got, base)):
+            assert sort_matches(a) == sort_matches(b), (kind, qi)
 
 
 def test_device_join_identity_under_delta(engine_and_queries):
@@ -278,11 +278,11 @@ def test_device_join_identity_under_delta(engine_and_queries):
                 remove_edges=e[rng.choice(e.shape[0], 3, replace=False)],
             )
         )
-        for pimpl in ("loop", "stacked"):
-            a = eng.match_many(queries, probe_impl=pimpl, join_impl="numpy")
-            b = eng.match_many(queries, probe_impl=pimpl, join_impl="device")
-            for qi, (x, y) in enumerate(zip(a, b)):
-                assert sort_matches(x) == sort_matches(y), (epoch, pimpl, qi)
+        a = eng.match_many(queries, join_impl="numpy")
+        b = eng.match_many(queries, join_impl="device")
+        for qi, (q, x, y) in enumerate(zip(queries, a, b)):
+            assert sort_matches(x) == sort_matches(y), (epoch, qi)
+            assert set(x) == set(vf2_match(eng.graph, q)), (epoch, qi)
 
 
 def test_stacked_device_join_no_host_expansion(engine_and_queries):
@@ -292,9 +292,9 @@ def test_stacked_device_join_no_host_expansion(engine_and_queries):
     eng, queries = engine_and_queries
     probe = eng.stacked_probe()
     before = probe.host_expansions
-    eng.match_many(queries, probe_impl="stacked", join_impl="device")
+    eng.match_many(queries, join_impl="device")
     assert probe.host_expansions == before, "device join expanded members on host"
-    eng.match_many(queries, probe_impl="stacked", join_impl="numpy")
+    eng.match_many(queries, join_impl="numpy")
     assert probe.host_expansions > before, "host path should count its expansions"
 
 
@@ -325,10 +325,13 @@ def test_isomorphic_queries_share_one_join_group(engine_and_queries):
 
 
 def test_scalar_impl_device_join(engine_and_queries):
+    """``match`` (a batch of one) takes the ``join_impl`` override; both
+    joins return VF2's match set."""
     eng, queries = engine_and_queries
-    a = eng.match(queries[0], impl="scalar", join_impl="numpy")
-    b = eng.match(queries[0], impl="scalar", join_impl="device")
+    a = eng.match(queries[0], join_impl="numpy")
+    b = eng.match(queries[0], join_impl="device")
     assert sort_matches(a) == sort_matches(b)
+    assert set(a) == set(vf2_match(eng.graph, queries[0]))
 
 
 def test_join_impl_validation(engine_and_queries):
@@ -446,25 +449,26 @@ def test_auto_group_size_engine_identical_matches():
     auto = GnnPeEngine(
         GnnPeConfig(
             encoder="monotone", n_partitions=3, n_multi=1, index_kind="grouped",
-            group_size_mode="auto", probe_impl="stacked",
+            group_size_mode="auto",
             train=TrainConfig(max_epochs=25),
         )
     ).build(g)
     sizes = auto.offline_stats["group_sizes"]
     assert sizes and all(s in (8, 16, 32) for s in sizes)
     a = fixed.match_many(qs)
-    # auto sizes must not change match sets, on either probe impl —
-    # including the stacked group sidecar with heterogeneous gpb
-    for pimpl in ("loop", "stacked"):
-        b = auto.match_many(qs, probe_impl=pimpl)
-        for qi, (x, y) in enumerate(zip(a, b)):
-            assert sort_matches(x) == sort_matches(y), (pimpl, qi)
+    # auto sizes must not change match sets — including the stacked
+    # group sidecar with heterogeneous gpb
+    b = auto.match_many(qs)
+    for qi, (q, x, y) in enumerate(zip(qs, a, b)):
+        assert sort_matches(x) == sort_matches(y), qi
+        assert set(y) == set(vf2_match(g, q)), qi
 
 
 def test_stacked_probe_heterogeneous_group_sizes():
     """Partitions grouped at DIFFERENT sizes (what auto mode produces on
     real data) must stack — slot capacity follows the finest grouping —
-    and probe identically to the loop traversal; a recompacted partition
+    and probe identically to the per-partition reference traversal
+    (``query_index_batch_multi``); a recompacted partition
     re-stacks in place iff its grouping fits the slot capacity."""
     from repro.core import build_index, query_index_batch_multi
     from repro.core.grouping import attach_groups

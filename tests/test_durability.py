@@ -43,8 +43,8 @@ from repro.serve.match_server import MatchServeConfig, MatchServer
 # ------------------------------------------------------------------ base ---
 
 CONFIGS = {
-    "path-loop": dict(index_kind="path", probe_impl="loop"),
-    "grouped-stacked": dict(index_kind="grouped", probe_impl="stacked"),
+    "path-stacked": dict(index_kind="path"),
+    "grouped-stacked": dict(index_kind="grouped"),
 }
 
 
@@ -299,7 +299,7 @@ def test_snapshot_byte_identity(base, name):
 
 def test_snapshot_corruption_falls_back(base, tmp_path):
     g, entries = base
-    eng = _clone(entries["path-loop"])
+    eng = _clone(entries["path-stacked"])
     dur = Durability(DurabilityConfig(str(tmp_path), genesis_snapshot=False))
     dur.snapshot(eng)
     eng.apply_updates([_stream(g, 1)[0]])
@@ -366,7 +366,7 @@ def test_crash_then_torn_write_recovers(base, tmp_path):
     last durable epoch — a state the no-crash replica also passed through."""
     g, entries = base
     stream = _stream(g, 5, seed=4)
-    victim = _clone(entries["path-loop"])
+    victim = _clone(entries["path-stacked"])
     dur = Durability(
         DurabilityConfig(str(tmp_path), snapshot_every=0, genesis_snapshot=False),
         crash=CrashPoint("after_log", at=4),
@@ -379,7 +379,7 @@ def test_crash_then_torn_write_recovers(base, tmp_path):
 
     recovered, info = recover_engine(DurabilityConfig(str(tmp_path)))
     assert info["epoch"] == 3 and info["truncated_bytes"] > 0
-    control = _clone(entries["path-loop"])
+    control = _clone(entries["path-stacked"])
     for u in stream[:3]:
         control.apply_updates([u])
     assert _identical(recovered, control, _queries(g))
@@ -387,7 +387,7 @@ def test_crash_then_torn_write_recovers(base, tmp_path):
 
 def test_crash_recovery_corrupt_wal_fails_loudly(base, tmp_path):
     g, entries = base
-    victim = _clone(entries["path-loop"])
+    victim = _clone(entries["path-stacked"])
     dur = Durability(DurabilityConfig(str(tmp_path), snapshot_every=0))
     srv = MatchServer(victim, MatchServeConfig(durability=dur))
     for u in _stream(g, 4, seed=6):
@@ -407,7 +407,7 @@ def test_recovery_without_snapshot_fails_loudly(tmp_path):
 
 def test_recovery_rejects_wal_gap(base, tmp_path):
     g, entries = base
-    victim = _clone(entries["path-loop"])
+    victim = _clone(entries["path-stacked"])
     dur = Durability(DurabilityConfig(str(tmp_path), snapshot_every=0, genesis_snapshot=False))
     dur.snapshot(victim)
     for u in _stream(g, 3, seed=8):
@@ -475,7 +475,7 @@ def test_standing_reregistration_exactly_once(base, tmp_path):
 
 def test_unsubscribe_survives_recovery(base, tmp_path):
     g, entries = base
-    victim = _clone(entries["path-loop"])
+    victim = _clone(entries["path-stacked"])
     dur = Durability(DurabilityConfig(str(tmp_path), snapshot_every=0))
     srv = MatchServer(victim, MatchServeConfig(durability=dur))
     q1, q2 = _queries(g, n=2, seed0=90)
@@ -504,7 +504,7 @@ def test_scrub_clean_and_detects(base):
     assert not bad["ok"]
     assert any(v["check"] == "mbr" for v in bad["violations"])
 
-    eng2 = _clone(entries["path-loop"])
+    eng2 = _clone(entries["path-stacked"])
     eng2.apply_updates([_stream(g, 1, seed=15)[0]])
     eng2.delta.parts[0].n_tomb += 1  # bookkeeping drift
     bad2 = scrub_engine(eng2)
@@ -513,7 +513,7 @@ def test_scrub_clean_and_detects(base):
 
 def test_server_scrub_admin_call(base, tmp_path):
     g, entries = base
-    eng = _clone(entries["path-loop"])
+    eng = _clone(entries["path-stacked"])
     srv = MatchServer(eng, MatchServeConfig())
     assert srv.scrub(sample=2)["ok"]
 
@@ -544,7 +544,7 @@ def test_server_genesis_and_durable_restart(base, tmp_path):
     """A durable server on a fresh directory snapshots its build (genesis)
     so recovery works even before the first update tick."""
     g, entries = base
-    eng = _clone(entries["path-loop"])
+    eng = _clone(entries["path-stacked"])
     cfg = DurabilityConfig(str(tmp_path), snapshot_every=2)
     MatchServer(eng, MatchServeConfig(durability=cfg))
     recovered, info = recover_engine(cfg)

@@ -248,14 +248,14 @@ STEPS = {
 }
 
 
-@pytest.mark.parametrize("probe_impl,join_impl", [("stacked", "device"), ("loop", "numpy")])
-def test_traced_match_many_records_every_step(monkeypatch, probe_impl, join_impl):
+@pytest.mark.parametrize("join_impl", ["device", "numpy"])
+def test_traced_match_many_records_every_step(monkeypatch, join_impl):
     """Each stage's steps sit under its span and inside its time, in the
-    trace and in the step histogram; the served path (stacked probe,
-    device join) has every step, the loop probe with the NumPy join only
-    the embedding's.  Device waits are counted, and a trace no longer
-    makes the stacked probe build per-partition stats."""
-    eng = _engine(index_kind="grouped", probe_impl=probe_impl, join_impl=join_impl)
+    trace and in the step histogram; the served path (device join) has
+    every step, the NumPy join the embedding's, the probe's host-side
+    prepare and none of the join's.  Device waits are counted, and a
+    trace no longer makes the stacked probe build per-partition stats."""
+    eng = _engine(index_kind="grouped", join_impl=join_impl)
     qs = _queries(eng.graph, n=3)
     eng.match_many(qs)  # warm compile outside the trace
 
@@ -263,7 +263,8 @@ def test_traced_match_many_records_every_step(monkeypatch, probe_impl, join_impl
         raise AssertionError("per-partition stats built without a dr cost model")
 
     monkeypatch.setattr(StackedProbe, "_device_probe_stats", no_stats)
-    served = probe_impl == "stacked"
+    served = join_impl == "device"
+    host_steps = {"embed": STEPS["embed"], "probe": ("prepare",), "join": ()}
     syncs0 = _counter_total("gnnpe_engine_device_syncs_total")
     stage0 = {s: _hist("gnnpe_engine_stage_seconds", stage=s) for s in STEPS}
     step0 = {
@@ -275,7 +276,7 @@ def test_traced_match_many_records_every_step(monkeypatch, probe_impl, join_impl
         eng.match_many(qs)
     for stage, steps in STEPS.items():
         (span,) = tr.root.find(stage)
-        want = steps if served or stage == "embed" else ()
+        want = steps if served else host_steps[stage]
         assert {c.name for c in span.children} == {f"{stage}.{x}" for x in want}, stage
         assert sum(c.duration_s for c in span.children) <= span.duration_s
         stage_s = _hist("gnnpe_engine_stage_seconds", stage=stage) - stage0[stage]
@@ -288,21 +289,43 @@ def test_traced_match_many_records_every_step(monkeypatch, probe_impl, join_impl
     assert syncs >= (1 + 3 + 3 if served else 1)  # embed; probe; join init, compact, refine
 
 
-def test_surviving_groups_counted_without_a_trace():
+def test_surviving_groups_counted_without_a_trace(monkeypatch):
     """The surviving-groups rung is always on: untraced, the stacked
-    probe's device sums equal the loop probe's count."""
+    probe's host and device leaf stages each count what the per-partition
+    reference traversal (``query_index_batch_multi``, which shares no code
+    with the stacked descent) counts on the same probes."""
     eng = _engine(index_kind="grouped")
     qs = _queries(eng.graph, n=3)
     assert TRACER.current() is None
+    probes = []
+    for name in ("probe", "probe_device"):
+        def record(self, q_emb, q_emb0, q_multi=None, q_label_hash=None, *a,
+                   _orig=getattr(StackedProbe, name), **kw):
+            probes.append((q_emb, q_emb0, q_multi, q_label_hash))
+            return _orig(self, q_emb, q_emb0, q_multi, q_label_hash, *a, **kw)
+
+        monkeypatch.setattr(StackedProbe, name, record)
+
+    def reference(q_emb, q_emb0, q_multi, qh):
+        items = [
+            (m.index, q_emb[mi], q_emb0[mi], None if q_multi is None else q_multi[:, mi], qh)
+            for mi, m in enumerate(eng.models)
+            if m.index.n_paths
+        ]
+        before = index_mod._SURVIVING_GROUPS.value
+        index_mod.query_index_batch_multi(items, use_pallas=False, use_groups=True)
+        return index_mod._SURVIVING_GROUPS.value - before
+
     counts = {}
-    for impl, jimpl in (("loop", "numpy"), ("stacked", "device"), ("stacked", "numpy")):
-        eng.match_many(qs, probe_impl=impl, join_impl=jimpl)  # warm
+    for jimpl in ("numpy", "device"):
+        eng.match_many(qs, join_impl=jimpl)  # warm
+        probes.clear()
         before = _counter_total("gnnpe_funnel_total", stage="surviving_groups")
-        eng.match_many(qs, probe_impl=impl, join_impl=jimpl)
-        counts[(impl, jimpl)] = (
-            _counter_total("gnnpe_funnel_total", stage="surviving_groups") - before
-        )
-    assert counts[("loop", "numpy")] > 0
+        eng.match_many(qs, join_impl=jimpl)
+        counts[jimpl] = _counter_total("gnnpe_funnel_total", stage="surviving_groups") - before
+        assert len(probes) == 1, (jimpl, len(probes))
+        counts[f"{jimpl} reference"] = reference(*probes[0])
+    assert counts["numpy"] > 0
     assert len(set(counts.values())) == 1, counts
 
 

@@ -1,8 +1,9 @@
 """Stacked-tensor partition index + sharded probe (core/stacked.py,
-dist/probe.py): probe equivalence with the per-partition loop traversal
-across index kinds / quantization / ragged partition shapes, shard-
-balanced layout, padding accounting, the 4-virtual-device shard_map
-path, and the plan-cache + pre-hashed-join satellites."""
+dist/probe.py): probe equivalence with the per-partition reference
+traversal (``query_index_batch_multi``) across index kinds /
+quantization / ragged partition shapes, shard-balanced layout, padding
+accounting, the 4-virtual-device shard_map path, and the plan-cache +
+pre-hashed-join satellites."""
 import os
 import subprocess
 import sys
@@ -242,23 +243,22 @@ def test_plan_shards_balanced_and_padding_reported():
 
 
 def test_engine_stacked_equals_loop_and_oracle():
-    """Engine-level byte identity between probe impls, against VF2, with
+    """Engine-level: the stacked probe's match sets equal VF2's and a
+    batch equals its queries matched alone (byte identity), with
     stacked padding overhead reported in offline_stats."""
     g = erdos_renyi(140, avg_degree=3.5, n_labels=4, seed=5)
     for seed, kind in [(0, "path"), (1, "grouped")]:
         cfg = GnnPeConfig(
             n_partitions=3, encoder="monotone", n_multi=seed, block_size=32,
             index_kind=kind, group_size=4, quantize_index=bool(seed),
-            probe_impl="stacked",
         )
         eng = GnnPeEngine(cfg).build(g)
         assert eng.offline_stats["stacked_bytes"] > 0
         assert "stacked_padding_frac" in eng.offline_stats
         queries = [random_connected_query(g, 4 + s % 3, seed=50 + s) for s in range(4)]
-        stacked = eng.match_many(queries)  # cfg default: stacked probe
-        loop = eng.match_many(queries, probe_impl="loop")
+        stacked = eng.match_many(queries)
         for qi, q in enumerate(queries):
-            assert stacked[qi] == loop[qi], f"{kind} q{qi}"
+            assert stacked[qi] == eng.match(q), f"{kind} q{qi}"
             assert set(stacked[qi]) == set(vf2_match(g, q))
 
 

@@ -3,7 +3,7 @@ update stream — edge/vertex add/remove, forced inline compaction,
 background compaction installs, rebuild epochs — the accumulated
 incremental match set (initial snapshot + applied deltas) must equal a
 from-scratch ``match_many`` on the current graph, across ``index_kind``
-× ``probe_impl`` × ``join_impl``.  Plus the cheap paths: untouched
+× ``join_impl`` (and the int8 leaf sidecar).  Plus the cheap paths: untouched
 subscriptions advance for free (no probe, no join), a tombstoned match
 edge retracts the match, and the serving tiers (MatchServer tick
 interleaving, MatchService async delivery with caps/shed/quarantine)
@@ -74,23 +74,24 @@ def _apply_delta(acc: set, delta) -> set:
 
 
 @pytest.mark.parametrize(
-    "kind,impl,join_impl",
+    "kind,join_impl,extra",
     [
-        ("path", "loop", "numpy"),
-        ("grouped", "loop", "numpy"),
-        ("path", "stacked", "numpy"),
-        ("grouped", "stacked", "device"),
-        ("path", "loop", "device"),
+        ("path", "numpy", {}),
+        ("grouped", "numpy", {}),
+        ("path", "device", {}),
+        ("grouped", "device", {}),
+        ("grouped", "device", {"quantize_index": True}),
     ],
 )
-def test_standing_equals_from_scratch_property(kind, impl, join_impl):
+def test_standing_equals_from_scratch_property(kind, join_impl, extra):
     """The headline gate: random update stream (edge add/remove, vertex
     add/remove, forced inline compaction at a tiny threshold), and at
     every epoch each subscription's accumulated set == match_many."""
     rng = np.random.default_rng(11)
     eng = _engine(
-        index_kind=kind, probe_impl=impl, join_impl=join_impl,
+        index_kind=kind, join_impl=join_impl,
         delta_compact_min=8,  # force real compactions mid-stream
+        **extra,
     )
     reg = StandingQueryRegistry(eng)
     qs = _queries(eng.graph)

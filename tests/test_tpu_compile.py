@@ -125,7 +125,7 @@ def test_stacked_probe_mask_and_cell_programs_compile(one_chip):
     g = erdos_renyi(300, avg_degree=4, n_labels=5, seed=1)
     probe = GnnPeEngine(GnnPeConfig(
         n_partitions=2, encoder="monotone", n_multi=2, emb_dim=2, index_kind="grouped",
-        group_size=16, block_size=128, probe_impl="stacked", seed=1,
+        group_size=16, block_size=128, seed=1,
     )).build(g).stacked_probe()
     st = probe.stacked
     S, Qp, n_lv = 100, 8, len(st.level_hi)
